@@ -45,6 +45,7 @@ from repro.kernels.histogram import histogram
 from repro.kernels.matmul import matmul
 from repro.kernels.nbody import nbody_accel
 from repro.kernels.stencil import jacobi4
+from repro.runtime.compile_cache import enable_compile_cache
 
 HW = TPU_V5E
 ROWS = []
@@ -943,6 +944,7 @@ def main(argv=None) -> None:
                          "visible devices, continuous-tp2 (sharded heads + "
                          "KV pools vs the single-device oracle)")
     args = ap.parse_args(argv)
+    print(f"[compile-cache] {enable_compile_cache()}")
     if args.tune:
         run_tune(args)
     elif args.train_grad:

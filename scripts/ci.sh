@@ -10,14 +10,13 @@
 #                           slow-marker threshold, no moves needed; target
 #                           smoke wall-time <= ~8 min.
 #   scripts/ci.sh full    — everything, incl. multi-device subprocess tests
-#   scripts/ci.sh lint    — compileall + compat-policy grep gates (no direct
+#   scripts/ci.sh lint    — compileall + policy grep gates (no direct
 #                           hypothesis imports outside the shim, no direct
 #                           jax.make_mesh(..., axis_types=...) outside
 #                           launch/mesh.py, no direct kernel-family imports
 #                           from models/ or launch/ — everything routes
 #                           through kernels.dispatch / kernels.registry —
-#                           and shard_map / mesh construction only via
-#                           runtime/compat.py + launch/mesh.py)
+#                           and mesh construction only via launch/mesh.py)
 #   scripts/ci.sh tune    — design-space sweep; writes results/tuned_plans.json
 #   scripts/ci.sh serve   — paged-serving smoke: interpret-mode ragged
 #                           prefill + decode through dispatch for a few
@@ -82,18 +81,7 @@ lint() {
          "through cfg.kv_dtype + repro.core.quant):"
     echo "$bad"; exit 1
   fi
-  # 5. shard_map enters the codebase through ONE shim
-  #    (runtime/compat.shard_map handles the jax.shard_map vs
-  #    jax.experimental.shard_map + check_vma/check_rep rename) and mesh
-  #    construction through launch/mesh.py — sharded serving must not
-  #    fork new version-feature-detection sites
-  bad=$(grep -rnE 'jax\.shard_map|experimental(\.| +import +)shard_map' \
-        src --include='*.py' | grep -v 'runtime/compat.py' || true)
-  if [ -n "$bad" ]; then
-    echo "lint: shard_map used outside runtime/compat.py" \
-         "(call repro.runtime.compat.shard_map):"
-    echo "$bad"; exit 1
-  fi
+  # 5. mesh construction goes through launch/mesh.py (Auto axis types)
   bad=$(grep -rnE 'jax\.make_mesh|sharding\.Mesh\(' src --include='*.py' \
         | grep -v 'launch/mesh.py' || true)
   if [ -n "$bad" ]; then

@@ -88,6 +88,31 @@ TPU_V5E = HardwareSpec(
     clock_hz=940e6,
 )
 
+# chips this repo has a spec for, keyed by ``jax.Device.device_kind`` (a
+# v5e reports "TPU v5 lite")
+HARDWARE_BY_KIND: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+}
+
+
+def device_hardware() -> HardwareSpec:
+    """The spec of the chip JAX computes on.
+
+    On a TPU backend it is looked up by the first device's ``device_kind``;
+    a TPU kind missing from ``HARDWARE_BY_KIND`` raises rather than being
+    planned as a v5e.  Every other backend runs the kernels in interpret
+    mode, and plans for the v5e, the design target."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return TPU_V5E
+    kind = jax.devices()[0].device_kind
+    if kind not in HARDWARE_BY_KIND:
+        raise ValueError(
+            f"no HardwareSpec for TPU device_kind {kind!r}; known kinds: "
+            f"{sorted(HARDWARE_BY_KIND)} (add one to core/model.py)")
+    return HARDWARE_BY_KIND[kind]
+
 
 @dataclass
 class Roofline:

@@ -38,12 +38,12 @@ def _quantize(x: jax.Array, scale: jax.Array) -> jax.Array:
 
 
 def quantize_pages(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Whole-page quantize: x (..., page, Hkv, hd) float ->
+    """Whole-page quantize: x (..., Hkv, page, hd) float ->
     (int8 same-shape, f32 scales (..., Hkv)) with one scale per
     (page, kv-head) — abs-max over the (page, hd) axes."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(-3, -1))
+    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(-2, -1))
     scale = amax / INT8_MAX                       # (..., Hkv)
-    q = _quantize(x, scale[..., None, :, None])
+    q = _quantize(x, scale[..., None, None])
     return q, scale
 
 
@@ -53,7 +53,7 @@ def append_token_quantized(page_q: jax.Array, page_scale: jax.Array,
     """Decode append: write one token into slot ``off`` of each gathered
     page with a running-max rescale.
 
-    page_q (B, page, Hkv, hd) int8 — the gathered per-slot pages;
+    page_q (B, Hkv, page, hd) int8 — the gathered per-slot pages;
     page_scale (B, Hkv) f32; token (B, Hkv, hd) float; off (B,) int32.
     The scale only grows (new = max(old, token_amax/127)); existing ints
     are rescaled by old/new, so a freshly reset page (scale 0) starts
@@ -64,10 +64,10 @@ def append_token_quantized(page_q: jax.Array, page_scale: jax.Array,
     ratio = jnp.where(new_scale > 0, page_scale / jnp.where(
         new_scale > 0, new_scale, 1.0), 0.0)
     page_q = jnp.clip(jnp.round(page_q.astype(jnp.float32)
-                                * ratio[:, None, :, None]),
+                                * ratio[:, :, None, None]),
                       -INT8_MAX, INT8_MAX).astype(jnp.int8)
     tok_q = _quantize(token, new_scale[..., None])             # (B, Hkv, hd)
-    page_q = page_q.at[jnp.arange(b), off].set(tok_q)
+    page_q = page_q.at[jnp.arange(b), :, off].set(tok_q)
     return page_q, new_scale
 
 

@@ -21,7 +21,7 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from .model import TPU_V5E, HardwareSpec
+from .model import TPU_V5E, HardwareSpec, device_hardware
 
 
 def round_up(x: int, to: int) -> int:
@@ -78,27 +78,32 @@ class TilePlanner:
 
     Working set per grid step for C[bm,bn] += A[bm,bk] @ B[bk,bn]:
         A-block + B-block (double-buffered: x2 for DMA overlap, the paper's
-        memory oversubscription §4.2) + C-accumulator (single, revisited).
+        memory oversubscription §4.2) + C-accumulator (single, revisited)
+        + the f32 C output block, which the pipeline double-buffers for its
+        write-back like the inputs.
     Larger bm*bn raises reuse of each loaded A/B element — the §3.2
     "replication fed by reuse" argument in shape form.
     """
 
-    def __init__(self, hw: HardwareSpec = TPU_V5E, *,
+    def __init__(self, hw: Optional[HardwareSpec] = None, *,
                  vmem_fraction: float = 0.75,
                  double_buffer: bool = True):
-        self.hw = hw
-        self.budget = int(hw.vmem_bytes * vmem_fraction)
+        # default: the chip JAX runs on (``device_hardware``), v5e off-TPU
+        self.hw = hw if hw is not None else device_hardware()
+        self.budget = int(self.hw.vmem_bytes * vmem_fraction)
         self.double_buffer = double_buffer
 
     def plan_from_tiles(self, m: int, n: int, k: int,
                         bm: int, bn: int, bk: int, *,
-                        in_bytes: int = 2, acc_bytes: int = 4) -> TilePlan:
+                        in_bytes: int = 2, acc_bytes: int = 4,
+                        out_bytes: int = 4) -> TilePlan:
         """Materialize the TilePlan for explicit (bm, bn, bk) tiles, or raise
         if the working set exceeds the VMEM budget.  This is the single
         feasibility check shared by the heuristic solver, the autotuner's
         space enumeration, and cache-deserialized plans."""
         buf = 2 if self.double_buffer else 1
-        vmem = (bm * bk + bk * bn) * in_bytes * buf + bm * bn * acc_bytes
+        vmem = ((bm * bk + bk * bn) * in_bytes + bm * bn * out_bytes) * buf \
+            + bm * bn * acc_bytes
         if vmem > self.budget:
             raise ValueError(
                 f"tiles ({bm},{bn},{bk}) need {vmem} bytes of VMEM, "
@@ -118,18 +123,21 @@ class TilePlanner:
         so `[0]`, when non-empty, is what ``plan_matmul`` returns."""
         cands = list(candidates or (128, 256, 512, 1024, 2048))
         mxu = self.hw.mxu_dim
-        plans: List[TilePlan] = []
-        for bm in cands:
+
+        def tiles(dim: int) -> List[int]:
             # tiles must divide the (clamped) problem dim: matmul_pallas
-            # shrinks b to min(b, dim) and rejects ragged grids
-            if bm > round_up(m, mxu) or m % min(bm, m):
-                continue
-            for bn in cands:
-                if bn > round_up(n, mxu) or n % min(bn, n):
-                    continue
-                for bk in cands:
-                    if bk > round_up(k, mxu) or k % min(bk, k):
-                        continue
+            # shrinks b to min(b, dim) and rejects ragged grids.  A dim no
+            # candidate divides (a tensor-parallel shard such as
+            # 13440 / 4 = 3360) takes one whole-dim block, which the TPU
+            # accepts at any size
+            return [b for b in cands
+                    if b <= round_up(dim, mxu) and not dim % min(b, dim)] \
+                or [dim]
+
+        plans: List[TilePlan] = []
+        for bm in tiles(m):
+            for bn in tiles(n):
+                for bk in tiles(k):
                     try:
                         plans.append(self.plan_from_tiles(
                             m, n, k, bm, bn, bk,

@@ -57,7 +57,8 @@ def _p_and_ds(q, k, v, do, lse, di, q_lo, k_lo, *, causal, window, scale):
 
     Returns (p, ds), both (bq, bkv) f32: p = exp(scale*qk^T - lse) under
     the causal/window mask, ds = p * (dP - delta) with dP = dO V^T.  The
-    shared tile every accumulator update is built from.
+    shared tile every accumulator update is built from.  ``lse`` and
+    ``di`` arrive as (bq, 1) columns, which broadcast along the key axis.
     """
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -67,9 +68,9 @@ def _p_and_ds(q, k, v, do, lse, di, q_lo, k_lo, *, causal, window, scale):
         mask &= kpos <= qpos
     if window > 0:
         mask &= kpos > qpos - window
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - di[:, None])
+    ds = p * (dp - di)
     return p, ds
 
 
@@ -149,14 +150,16 @@ def flash_attention_bwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = 1.0 / math.sqrt(hd)
 
     qf, kf, vf, dof = (t.reshape(bh, s, hd) for t in (q, k, v, do))
-    lsef = lse.reshape(bh, s)
+    # per-row lse / delta ride as (bh, s, 1) columns: a (1, block_q, 1)
+    # block tiles on the TPU, where a (1, block_q) row block does not
+    lsef = lse.reshape(bh, s, 1)
     # delta = rowsum(dO * O): O(S*hd) precompute shared by both kernels
     dif = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
-                  axis=-1).reshape(bh, s)
+                  axis=-1).reshape(bh, s, 1)
 
     q_spec = pl.BlockSpec((1, block_q, hd), lambda g, i, j: (g, i, 0))
     kv_spec = pl.BlockSpec((1, block_kv, hd), lambda g, i, j: (g, j, 0))
-    row_spec = pl.BlockSpec((1, block_q), lambda g, i, j: (g, i))
+    row_spec = pl.BlockSpec((1, block_q, 1), lambda g, i, j: (g, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, n_kv=n_kv, block_q=block_q,
@@ -176,7 +179,7 @@ def flash_attention_bwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     # index-map grid coordinates so i walks Q tiles for a fixed KV tile
     q_spec_i = pl.BlockSpec((1, block_q, hd), lambda g, j, i: (g, i, 0))
     kv_spec_i = pl.BlockSpec((1, block_kv, hd), lambda g, j, i: (g, j, 0))
-    row_spec_i = pl.BlockSpec((1, block_q), lambda g, j, i: (g, i))
+    row_spec_i = pl.BlockSpec((1, block_q, 1), lambda g, j, i: (g, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, n_q=n_q, block_q=block_q,
                           block_kv=block_kv, causal=causal, window=window,

@@ -20,9 +20,12 @@ shared pool.  This is the paper's memory stack applied to a cache:
   than its window) are skipped with ``pl.when`` before any MXU work.
 
 Layout: q (B, H, hd) — one token per slot, GQA-grouped to (B, Hkv, grp,
-hd); k_pages / v_pages (P, page, Hkv, hd); table (B, n_pages) int32 page
-ids (row j is the slot's j-th logical page); lengths (B,) int32 valid
-tokens per slot (0 = inactive slot -> zero output, no NaNs).
+hd); k_pages / v_pages (P, Hkv, page, hd) — head-major, so one page of
+one kv head is a contiguous (page, hd) tile whose BlockSpec (1, 1, page,
+hd) satisfies the TPU's (8, 128) tiling on its two minor dims; table (B,
+n_pages) int32 page ids (row j is the slot's j-th logical page); lengths
+(B,) int32 valid tokens per slot (0 = inactive slot -> zero output, no
+NaNs).
 """
 from __future__ import annotations
 
@@ -65,8 +68,8 @@ def _decode_kernel(lengths_ref, table_ref, *rest, n_tiles: int,
         # per-kv-head scale, fetched through the same scalar-prefetch path
         # that resolved the physical page id (§4.1)
         if scale_ref is None:
-            return jnp.concatenate([r[0, :, 0] for r in refs_], axis=0)
-        tiles = [r[0, :, 0].astype(jnp.float32)
+            return jnp.concatenate([r[0, 0] for r in refs_], axis=0)
+        tiles = [r[0, 0].astype(jnp.float32)
                  * scale_ref[table_ref[b, j * ppt + i], hh]
                  for i, r in enumerate(refs_)]
         return jnp.concatenate(tiles, axis=0)
@@ -118,7 +121,7 @@ def decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
                             v_scale: jax.Array = None, *, window: int = 0,
                             pages_per_tile: int = 1,
                             interpret: bool = False) -> jax.Array:
-    """q (B, H, hd); k/v_pages (P, page, Hkv, hd); table (B, n_pages);
+    """q (B, H, hd); k/v_pages (P, Hkv, page, hd); table (B, n_pages);
     lengths (B,).  Returns (B, H, hd) f32.
 
     int8 pools additionally take ``k_scale`` / ``v_scale`` (P, Hkv) f32
@@ -126,7 +129,7 @@ def decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
     to ``table`` and the page tiles dequantize at load time."""
     quantized = k_scale is not None
     b, h, hd = q.shape
-    _, page, hkv, _ = k_pages.shape
+    _, hkv, page, _ = k_pages.shape
     n_pages = table.shape[1]
     assert h % hkv == 0, (h, hkv)
     grp = h // hkv
@@ -151,9 +154,9 @@ def decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
         # [j*ppt, (j+1)*ppt); the scalar-prefetched table resolves the
         # logical -> physical page id inside the index map (§4.1)
         return pl.BlockSpec(
-            (1, page, 1, hd),
+            (1, 1, page, hd),
             lambda bb, hh, jj, lens, tab, *_sc, i=i: (tab[bb, jj * ppt + i],
-                                                      0, hh, 0))
+                                                      hh, 0, 0))
 
     q_spec = pl.BlockSpec((1, 1, grp, hd),
                           lambda bb, hh, jj, lens, tab, *_sc: (bb, hh, 0, 0))

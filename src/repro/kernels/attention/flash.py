@@ -81,9 +81,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                     / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
         if with_lse:
             # per-row logsumexp m + log(l): the only residual the fused
-            # backward needs to recompute P tiles (ISSUE: store lse, not P)
-            lse = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
-            lse_ref[...] = lse.reshape(1, block_q)
+            # backward needs to recompute P tiles (store lse, not P)
+            # (block_q, 1) column, stored as-is: the residual keeps a
+            # trailing unit axis so its block (1, block_q, 1) tiles on
+            # the TPU without an in-kernel relayout
+            lse_ref[0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
 
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -115,8 +117,9 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out_specs = [pl.BlockSpec((1, block_q, hd), lambda g, i, j: (g, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((bh, s, hd), jnp.float32)]
     if return_residuals:
-        out_specs.append(pl.BlockSpec((1, block_q), lambda g, i, j: (g, i)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, s), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, block_q, 1),
+                                      lambda g, i, j: (g, i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, s, 1), jnp.float32))
     outs = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_kv),

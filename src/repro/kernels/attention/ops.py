@@ -165,8 +165,8 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      interpret: Optional[bool] = None) -> jax.Array:
     """Ragged decode attention over a paged KV cache.
 
-    q (B, H, hd) — one query token per slot; k_pages / v_pages (P, page,
-    Hkv, hd) shared page pools; table (B, n_pages) int32 logical->physical
+    q (B, H, hd) — one query token per slot; k_pages / v_pages (P, Hkv,
+    page, hd) shared page pools; table (B, n_pages) int32 logical->physical
     page ids; lengths (B,) int32 valid tokens per slot (0 = inactive slot,
     output 0).  int8 pools additionally take ``k_scale`` / ``v_scale``
     (P, Hkv) f32 per-page per-kv-head scales (in-kernel dequant, §4.4).
@@ -184,7 +184,7 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if interpret is None:
         interpret = interpret_default()
     b, h, hd = q.shape
-    _, page, _, _ = k_pages.shape
+    page = k_pages.shape[2]
     n_pages = table.shape[1]
     shape = (b, h, n_pages, page, hd)
     level, kw = resolve_plan("decode_attention", shape, k_pages.dtype,
@@ -225,7 +225,7 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Ragged multi-token prefill attention over a paged KV cache.
 
     q (B, C, H, hd) — one chunk of C prompt tokens per slot, already
-    written into the pools; k_pages / v_pages (P, page, Hkv, hd) shared
+    written into the pools; k_pages / v_pages (P, Hkv, page, hd) shared
     page pools; table (B, n_pages) int32 page ids; starts (B,) int32
     page-aligned chunk offsets (slot b's queries sit at positions
     ``starts[b] + [0, C)``).  int8 pools additionally take ``k_scale`` /
@@ -241,7 +241,7 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if interpret is None:
         interpret = interpret_default()
     b, c, h, hd = q.shape
-    _, page, _, _ = k_pages.shape
+    page = k_pages.shape[2]
     n_pages = table.shape[1]
     shape = (b, c, h, n_pages, page, hd)
     level, kw = resolve_plan("prefill_attention", shape, k_pages.dtype,
@@ -377,7 +377,7 @@ def decode_attention_reference(q, k_pages, v_pages, table, lengths,
     per-slot length (and window), softmax in ``accum_dtype``.  The einsum
     lowering the paged serve path uses when the kernel route is off."""
     b, h, hd = q.shape
-    _, page, hkv, _ = k_pages.shape
+    hkv = k_pages.shape[1]
     grp = h // hkv
     k = ref._gather_pages(k_pages, table, k_scale)
     v = ref._gather_pages(v_pages, table, v_scale)
@@ -411,7 +411,7 @@ def prefill_attention_reference(q, k_pages, v_pages, table, starts,
     ``accum_dtype`` — numerically identical to the gather +
     naive-attention path chunked prefill took before this op existed."""
     b, c, h, hd = q.shape
-    _, page, hkv, _ = k_pages.shape
+    hkv, page = k_pages.shape[1], k_pages.shape[2]
     grp = h // hkv
     registry.assert_no_dense_scores("prefill_attention_reference",
                                     c, table.shape[1] * page)
@@ -555,7 +555,7 @@ def _paged_pools_ok(q, k_pages, v_pages, k_scale, v_scale) -> bool:
                    for t in (k_pages, v_pages))
     if v_scale is None:
         return False
-    expect = (k_pages.shape[0], k_pages.shape[2])
+    expect = (k_pages.shape[0], k_pages.shape[1])
     return (all(t.dtype == jnp.int8 for t in (k_pages, v_pages))
             and all(jnp.issubdtype(s.dtype, jnp.floating)
                     and s.shape == expect for s in (k_scale, v_scale)))
@@ -565,14 +565,14 @@ def _decode_eligible(st, q, k_pages, v_pages, table, lengths,
                      k_scale=None, v_scale=None) -> bool:
     if st["softcap"] > 0:
         return False
-    if q.shape[1] % k_pages.shape[2]:
+    if q.shape[1] % k_pages.shape[1]:
         return False              # GQA group must divide evenly
     return _paged_pools_ok(q, k_pages, v_pages, k_scale, v_scale)
 
 
 def _decode_plan_shape(st, q, k_pages, v_pages, table, lengths,
                        k_scale=None, v_scale=None):
-    return (q.shape[0], q.shape[1], table.shape[1], k_pages.shape[1],
+    return (q.shape[0], q.shape[1], table.shape[1], k_pages.shape[2],
             q.shape[2])
 
 
@@ -604,8 +604,8 @@ def _paged_pool_inputs(dtype, *, slots=3, page=8, n_pages=3, h=4, hkv=2,
                        hd=16, seed=0):
     pool = 1 + slots * n_pages
     ks = jax.random.split(jax.random.key(seed), 3)
-    kp = jax.random.normal(ks[1], (pool, page, hkv, hd), dtype)
-    vp = jax.random.normal(ks[2], (pool, page, hkv, hd), dtype)
+    kp = jax.random.normal(ks[1], (pool, hkv, page, hd), dtype)
+    vp = jax.random.normal(ks[2], (pool, hkv, page, hd), dtype)
     table = (1 + jax.random.permutation(jax.random.key(seed + 1), pool - 1)
              [:slots * n_pages].reshape(slots, n_pages)).astype(jnp.int32)
     return ks[0], kp, vp, table
@@ -631,7 +631,7 @@ def _prefill_eligible(st, q, k_pages, v_pages, table, starts,
                       k_scale=None, v_scale=None) -> bool:
     if st["softcap"] > 0:
         return False
-    if q.shape[2] % k_pages.shape[2]:
+    if q.shape[2] % k_pages.shape[1]:
         return False              # GQA group must divide evenly
     return _paged_pools_ok(q, k_pages, v_pages, k_scale, v_scale)
 
@@ -639,7 +639,7 @@ def _prefill_eligible(st, q, k_pages, v_pages, table, starts,
 def _prefill_plan_shape(st, q, k_pages, v_pages, table, starts,
                         k_scale=None, v_scale=None):
     return (q.shape[0], q.shape[1], q.shape[2], table.shape[1],
-            k_pages.shape[1], q.shape[3])
+            k_pages.shape[2], q.shape[3])
 
 
 def _prefill_ref_lowering(ctx, q, k_pages, v_pages, table, starts,
@@ -707,7 +707,7 @@ def _flash_bwd_tune_call(args, plan):
 def _tune_pool(key, pool, page, hkv, hd, dtype):
     """One tune-cell page pool at ``dtype``; int8 returns (pool, scales)
     through the same abs-max quantizer the serve path writes with."""
-    vals = jax.random.normal(key, (pool, page, hkv, hd), jnp.float32)
+    vals = jax.random.normal(key, (pool, hkv, page, hd), jnp.float32)
     if jnp.dtype(dtype) == jnp.int8:
         from ...core.quant import quantize_pages
         return quantize_pages(vals)
@@ -838,12 +838,12 @@ registry.register(registry.OpSpec(
     bad_example=_decode_bad_example,
     tp={
         # heads are the sharded axis: q (B, H, hd) on dim 1, K/V pools
-        # (P, page, Hkv, hd) on dim 2, per-page scales (P, Hkv) on dim 1;
+        # (P, Hkv, page, hd) on dim 1, per-page scales (P, Hkv) on dim 1;
         # table/lengths are host metadata, replicated. Each shard attends
         # its own heads against its own pool slice, then the per-shard
         # (B, H/tp, hd) outputs all-gather back to full heads on dim 1.
         "heads": registry.TPContract(
-            in_axes=(1, 2, 2, None, None, 1, 1),
+            in_axes=(1, 1, 1, None, None, 1, 1),
             collective="all_gather",
             gather_axis=1,
         ),
@@ -862,10 +862,10 @@ registry.register(registry.OpSpec(
     bad_example=_prefill_bad_example,
     tp={
         # same layout as decode with a chunk axis: q (B, C, H, hd) sharded
-        # on dim 2, pools on dim 2, scales on dim 1; gather restores full
+        # on dim 2, pools on dim 1, scales on dim 1; gather restores full
         # heads on dim 2 of the (B, C, H/tp, hd) per-shard output.
         "heads": registry.TPContract(
-            in_axes=(2, 2, 2, None, None, 1, 1),
+            in_axes=(2, 1, 1, None, None, 1, 1),
             collective="all_gather",
             gather_axis=2,
         ),

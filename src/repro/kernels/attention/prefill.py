@@ -24,7 +24,8 @@ still take the reference attention route" item with the same paper stack:
 
 Layout: q (B, C, H, hd) — B chunked slots, GQA-grouped to (B, Hkv, C*grp,
 hd) so each grid step feeds one (C*grp, page*ppt) MXU score tile;
-k_pages / v_pages (P, page, Hkv, hd); table (B, n_pages) int32 page ids;
+k_pages / v_pages (P, Hkv, page, hd) (head-major: one kv head's page is
+a contiguous (page, hd) tile); table (B, n_pages) int32 page ids;
 starts (B,) int32 page-aligned chunk offsets — slot b's queries sit at
 positions ``starts[b] + [0, C)`` and its live KV length is
 ``starts[b] + C`` (the chunk was just written into its page).  Padded
@@ -66,8 +67,8 @@ def _prefill_kernel(starts_ref, table_ref, *rest, n_tiles: int,
         # per-kv-head scale, fetched through the same scalar-prefetch path
         # that resolved the physical page id (§4.1)
         if scale_ref is None:
-            return jnp.concatenate([r[0, :, 0] for r in refs_], axis=0)
-        tiles = [r[0, :, 0].astype(jnp.float32)
+            return jnp.concatenate([r[0, 0] for r in refs_], axis=0)
+        tiles = [r[0, 0].astype(jnp.float32)
                  * scale_ref[table_ref[b, j * ppt + i], hh]
                  for i, r in enumerate(refs_)]
         return jnp.concatenate(tiles, axis=0)
@@ -125,7 +126,7 @@ def prefill_attention_pallas(q: jax.Array, k_pages: jax.Array,
                              v_scale: jax.Array = None, *, window: int = 0,
                              pages_per_tile: int = 1,
                              interpret: bool = False) -> jax.Array:
-    """q (B, C, H, hd); k/v_pages (P, page, Hkv, hd); table (B, n_pages);
+    """q (B, C, H, hd); k/v_pages (P, Hkv, page, hd); table (B, n_pages);
     starts (B,) page-aligned chunk offsets.  Returns (B, C, H, hd) f32.
 
     int8 pools additionally take ``k_scale`` / ``v_scale`` (P, Hkv) f32
@@ -133,7 +134,7 @@ def prefill_attention_pallas(q: jax.Array, k_pages: jax.Array,
     to ``table`` and the page tiles dequantize at load time."""
     quantized = k_scale is not None
     b, c, h, hd = q.shape
-    _, page, hkv, _ = k_pages.shape
+    _, hkv, page, _ = k_pages.shape
     n_pages = table.shape[1]
     assert h % hkv == 0, (h, hkv)
     grp = h // hkv
@@ -163,9 +164,9 @@ def prefill_attention_pallas(q: jax.Array, k_pages: jax.Array,
         # [j*ppt, (j+1)*ppt); the scalar-prefetched table resolves the
         # logical -> physical page id inside the index map (§4.1)
         return pl.BlockSpec(
-            (1, page, 1, hd),
+            (1, 1, page, hd),
             lambda bb, hh, jj, st, tab, *_sc, i=i: (tab[bb, jj * ppt + i],
-                                                    0, hh, 0))
+                                                    hh, 0, 0))
 
     q_spec = pl.BlockSpec((1, 1, rows, hd),
                           lambda bb, hh, jj, st, tab, *_sc: (bb, hh, 0, 0))

@@ -9,15 +9,16 @@ import jax.numpy as jnp
 
 def _gather_pages(pages: jax.Array, table: jax.Array,
                   scale: jax.Array) -> jax.Array:
-    """Gather a pool's pages per slot; int8 pools (scale (P, Hkv) f32
+    """Gather a (P, Hkv, page, hd) pool's pages per slot into a dense
+    (B, n_pages * page, Hkv, hd) view; int8 pools (scale (P, Hkv) f32
     per-page per-kv-head) dequantize to f32 at gather time — the oracle
     twin of the kernels' in-tile dequant."""
     b = table.shape[0]
-    hkv, hd = pages.shape[2], pages.shape[3]
-    g = pages[table]                       # (B, n_pages, page, Hkv, hd)
+    hkv, hd = pages.shape[1], pages.shape[3]
+    g = pages[table]                       # (B, n_pages, Hkv, page, hd)
     if scale is not None:
-        g = g.astype(jnp.float32) * scale[table][:, :, None, :, None]
-    return g.reshape(b, -1, hkv, hd)
+        g = g.astype(jnp.float32) * scale[table][:, :, :, None, None]
+    return g.transpose(0, 1, 3, 2, 4).reshape(b, -1, hkv, hd)
 
 
 def decode_attention_ref(q: jax.Array, k_pages: jax.Array,
@@ -31,7 +32,7 @@ def decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     mask key positions past each slot's length (and older than its
     window), f32 softmax.  q (B, H, hd) -> (B, H, hd) f32."""
     b, h, hd = q.shape
-    _, page, hkv, _ = k_pages.shape
+    hkv = k_pages.shape[1]
     grp = h // hkv
     k = _gather_pages(k_pages, table, k_scale)       # (B, n_pages*page, ...)
     v = _gather_pages(v_pages, table, v_scale)
@@ -67,7 +68,7 @@ def prefill_attention_ref(q: jax.Array, k_pages: jax.Array,
     the pool) and by the sliding window, f32 softmax.
     q (B, C, H, hd) -> (B, C, H, hd) f32."""
     b, c, h, hd = q.shape
-    _, page, hkv, _ = k_pages.shape
+    hkv = k_pages.shape[1]
     grp = h // hkv
     k = _gather_pages(k_pages, table, k_scale)       # (B, n_pages*page, ...)
     v = _gather_pages(v_pages, table, v_scale)

@@ -220,8 +220,8 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      policy: PolicyLike = None) -> jax.Array:
     """Ragged decode attention over a paged KV cache — the serving hot path.
 
-    q (B, H, hd) one query token per slot; k_pages / v_pages (P, page,
-    Hkv, hd) shared pools; table (B, n_pages) logical->physical page ids;
+    q (B, H, hd) one query token per slot; k_pages / v_pages (P, Hkv,
+    page, hd) shared pools; table (B, n_pages) logical->physical page ids;
     lengths (B,) valid tokens per slot (0 = inactive -> zero output).
     int8 pools additionally pass ``k_scale`` / ``v_scale`` (P, Hkv) f32
     per-page per-kv-head scales (both or neither); the kernel dequantizes

@@ -8,42 +8,23 @@ when pipeline parallelism is enabled) because inter-pod links are the
 scarcest bandwidth — the paper's "routing" objective (Tab. 1 RT) maps to
 keeping traffic off that axis.
 
-JAX-version compat: ``jax.make_mesh`` grew an ``axis_types`` kwarg (and
-``jax.sharding.AxisType``) only after 0.4.x.  ``make_mesh`` below is the
-single version-tolerant entry point — it requests Auto axis types when the
-installed JAX supports them and silently omits them otherwise, so every
-caller (production meshes, tests, subprocess snippets) works on both sides
-of the API change.
+``make_mesh`` is the one place meshes are built: every axis is an Auto
+axis, so GSPMD propagates shardings through the un-annotated model code.
 """
 from __future__ import annotations
 
-import functools
-import inspect
 from typing import Optional, Sequence
 
 import jax
 
 
-@functools.lru_cache(maxsize=1)
-def _axis_types_supported() -> bool:
-    if not hasattr(jax.sharding, "AxisType"):
-        return False
-    try:
-        params = inspect.signature(jax.make_mesh).parameters
-    except (TypeError, ValueError):
-        return False
-    return "axis_types" in params
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API allows them."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _axis_types_supported():
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

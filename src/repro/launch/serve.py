@@ -208,7 +208,7 @@ class PageAllocator:
 
 def _copy_cache_page(cache, src, dst):
     """Copy one physical page across every layer's K/V pools (the
-    copy-on-write payload).  Pool leaves are (P, page, Hkv, hd) and, for
+    copy-on-write payload).  Pool leaves are (P, Hkv, page, hd) and, for
     quantized pools, (P, Hkv) scale rows; scanned layer stacks carry a
     leading period axis (ndim 5 / 3).  Scales ride the same copy so a
     CoW'd page dequantizes identically to its source."""
@@ -246,7 +246,7 @@ def _reset_page_scales(cache, pages):
 def _page_bytes(cache) -> int:
     """Bytes ONE physical page occupies across every cache leaf — K/V
     pools at the active storage dtype plus any scale rows.  Pool axis is
-    0 for per-layer leaves ((P, page, Hkv, hd) pools, (P, Hkv) scales)
+    0 for per-layer leaves ((P, Hkv, page, hd) pools, (P, Hkv) scales)
     and 1 for scanned stacks with a leading period axis."""
     total = 0
     for leaf in jax.tree.leaves(cache):
@@ -1017,7 +1017,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from ..kernels import dispatch
+    from ..runtime.compile_cache import enable_compile_cache
     from ..tune.cache import preload as preload_tuned
+    print(f"[compile-cache] {enable_compile_cache()}")
     preload_tuned(log=print)
     cfg = get_arch(args.arch)
     if args.smoke:

@@ -61,7 +61,9 @@ def main(argv=None):
                          "(repro.kernels.dispatch)")
     args = ap.parse_args(argv)
 
+    from ..runtime.compile_cache import enable_compile_cache
     from ..tune.cache import preload as preload_tuned
+    print(f"[compile-cache] {enable_compile_cache()}")
     preload_tuned(log=print)
     cfg = get_arch(args.arch)
     if args.smoke:

@@ -313,13 +313,13 @@ def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
     the new token lands at position ``lengths[b]`` (the scheduler must
     have a page allocated there; inactive slots point at the trash page).
     table: (B, n_pages) int32 logical->physical page ids into the shared
-    (P, page, Hkv, hd) pools.  int8 pools additionally carry ``k_scale`` /
+    (P, Hkv, page, hd) pools.  int8 pools additionally carry ``k_scale`` /
     ``v_scale`` (P, Hkv) f32: the append runs the running-max requantize
     (``core.quant``) and the scales ride into the kernel's scalar-prefetch
     path.  Returns (out (B,1,d), k_pages, v_pages, k_scale, v_scale).
     """
     b = x.shape[0]
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     positions = (positions_override if positions_override is not None
                  else lengths[:, None].astype(jnp.int32))
     q, k, v = _qkv(p, s, x, positions, dt)
@@ -340,8 +340,9 @@ def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
         k_scale = k_scale.at[pid].set(sk)
         v_scale = v_scale.at[pid].set(sv)
     else:
-        k_pages = k_pages.at[pid, off].set(k[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[pid, off].set(v[:, 0].astype(v_pages.dtype))
+        # (pid, :, off) picks each slot's token row across all kv heads
+        k_pages = k_pages.at[pid, :, off].set(k[:, 0].astype(k_pages.dtype))
+        v_pages = v_pages.at[pid, :, off].set(v[:, 0].astype(v_pages.dtype))
     # GQA grouping happens inside the decode kernel/reference, so the
     # pools stay at Hkv heads end-to-end (no expanded copy in HBM)
     out = dispatch.decode_attention(
@@ -376,22 +377,24 @@ def attention_prefill_paged(p: Params, s: AttnSpec, x: jax.Array,
     Returns (out (B,C,d), k_pages, v_pages, k_scale, v_scale).
     """
     b, c, _ = x.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     positions = (positions_override if positions_override is not None
                  else (starts[:, None] + jnp.arange(c)[None, :]
                        ).astype(jnp.int32))
     q, k, v = _qkv(p, s, x, positions, dt)
     pid = tables[jnp.arange(b), starts // page]
+    # (B, C=page, Hkv, hd) -> the pools' head-major (B, Hkv, page, hd)
+    kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     if k_scale is not None:
-        pk, sk = quant.quantize_pages(k)       # k (B, C=page, Hkv, hd)
-        pv, sv = quant.quantize_pages(v)
+        pk, sk = quant.quantize_pages(kt)
+        pv, sv = quant.quantize_pages(vt)
         k_pages = k_pages.at[pid].set(pk)
         v_pages = v_pages.at[pid].set(pv)
         k_scale = k_scale.at[pid].set(sk)
         v_scale = v_scale.at[pid].set(sv)
     else:
-        k_pages = k_pages.at[pid].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[pid].set(v.astype(v_pages.dtype))
+        k_pages = k_pages.at[pid].set(kt.astype(k_pages.dtype))
+        v_pages = v_pages.at[pid].set(vt.astype(v_pages.dtype))
     # multi-token ragged prefill through dispatch: each chunk's queries
     # attend causally over the cached history plus the chunk itself (just
     # written into its page); GQA grouping happens inside the kernel /
@@ -432,7 +435,7 @@ def attention_verify_paged(p: Params, s: AttnSpec, x: jax.Array,
     Returns (out (B,W,d), k_pages, v_pages, k_scale, v_scale).
     """
     b, w, _ = x.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     positions = (positions_override if positions_override is not None
                  else (lengths[:, None] + jnp.arange(w)[None, :]
                        ).astype(jnp.int32))
@@ -459,8 +462,10 @@ def attention_verify_paged(p: Params, s: AttnSpec, x: jax.Array,
             k_scale = k_scale.at[pid].set(sk)
             v_scale = v_scale.at[pid].set(sv)
         else:
-            k_pages = k_pages.at[pid, off].set(k[:, t].astype(k_pages.dtype))
-            v_pages = v_pages.at[pid, off].set(v[:, t].astype(v_pages.dtype))
+            k_pages = k_pages.at[pid, :, off].set(
+                k[:, t].astype(k_pages.dtype))
+            v_pages = v_pages.at[pid, :, off].set(
+                v[:, t].astype(v_pages.dtype))
     out = dispatch.prefill_attention(
         q, k_pages, v_pages, table, lengths, k_scale, v_scale,
         window=s.window, softcap=s.softcap, accum_dtype=dt.accum,

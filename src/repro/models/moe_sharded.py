@@ -38,7 +38,6 @@ from ..core.memory import DtypePolicy
 from ..kernels import dispatch as kdispatch
 from .layers import mlp_apply
 from .moe import MoESpec, _act
-from ..runtime.compat import shard_map
 
 Params = Dict[str, jax.Array]
 
@@ -161,7 +160,7 @@ def moe_apply_sharded(p: Params, s: MoESpec, x: jax.Array, dt: DtypePolicy,
         combined = jnp.zeros((bl * sl, d), cdt).at[st].add(per_assign)
         return combined.reshape(bl, sl, d), aux
 
-    body_sm = shard_map(
+    body_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), wgu_spec, wgu_spec, wd_spec),
         out_specs=(x_spec, P()),

@@ -277,12 +277,15 @@ def layer_cache_init_paged(cfg: ArchConfig, kind: LayerKind, slots: int,
                            total_pages: int, page_size: int,
                            dtype) -> Dict[str, Any]:
     """Paged twin of ``layer_cache_init``: attention layers get shared
-    (P, page, Hkv, hd) page pools instead of per-slot rectangles;
+    (P, Hkv, page, hd) page pools instead of per-slot rectangles;
     recurrent state stays per-slot (it is O(1) per sequence already)."""
     mixer, ffn = kind
     cache: Dict[str, Any] = {}
     if mixer in ("attn", "swa"):
-        shape = (total_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        # head-major: one kv head's page is a contiguous (page, hd) tile,
+        # the block the paged kernels DMA (TPU tiling wants the two minor
+        # block dims to be (page, hd), never a size-1 head slice)
+        shape = (total_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
         cache["k_pages"] = jnp.zeros(shape, dtype)
         cache["v_pages"] = jnp.zeros(shape, dtype)
         if jnp.dtype(dtype) == jnp.int8:
@@ -665,7 +668,7 @@ class Model:
     def init_paged_cache(self, slots: int, max_len: int, page_size: int,
                          total_pages: Optional[int] = None
                          ) -> Dict[str, Any]:
-        """Paged KV cache: per-attention-layer (P, page, Hkv, hd) pools.
+        """Paged KV cache: per-attention-layer (P, Hkv, page, hd) pools.
 
         Physical page 0 is the TRASH page — the scheduler points inactive
         slots' tables at it so their (masked, discarded) decode writes

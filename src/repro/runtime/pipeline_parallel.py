@@ -23,7 +23,6 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
 
 
 def pipeline_apply(
@@ -82,7 +81,7 @@ def pipeline_apply(
             stage_axis)
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),

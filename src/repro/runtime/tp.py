@@ -39,7 +39,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..kernels import registry
-from . import compat
 
 
 # --------------------------------------------------------------------------
@@ -115,7 +114,7 @@ def param_pspecs(params, cfg, tp: int, *, axis: str = "model"):
 
 def cache_pspecs(cache, cfg, tp: int, *, axis: str = "model"):
     """PartitionSpec tree for a ``Model.init_paged_cache`` tree: pools
-    (P, page, Hkv, hd) shard their kv-head axis (ndim-2), scales (P, Hkv)
+    (P, Hkv, page, hd) shard their kv-head axis (ndim-3), scales (P, Hkv)
     shard ndim-1 — or everything replicates under MQA / tp == 1."""
     kv = kv_sharded(cfg, tp)
 
@@ -123,7 +122,7 @@ def cache_pspecs(cache, cfg, tp: int, *, axis: str = "model"):
         names = _path_names(path)
         name = names[-1] if names else ""
         if kv and name in ("k_pages", "v_pages"):
-            return _dim_spec(leaf.ndim, leaf.ndim - 2, axis)
+            return _dim_spec(leaf.ndim, leaf.ndim - 3, axis)
         if kv and name in ("k_scale", "v_scale"):
             return _dim_spec(leaf.ndim, leaf.ndim - 1, axis)
         return P()
@@ -144,7 +143,7 @@ def shard_tree(tree, specs, mesh):
 
 def sharded_paged_fns(model, mesh, *, axis: str = "model"):
     """(decode_fn, prefill_fn) running the model's paged steps under
-    ``compat.shard_map`` with ``registry.tp_scope`` active in the body.
+    ``jax.shard_map`` with ``registry.tp_scope`` active in the body.
 
     Both take the same signatures as ``Model.decode_step`` /
     ``Model.prefill_step_paged`` (params and cache pre-sharded via
@@ -172,7 +171,7 @@ def sharded_paged_fns(model, mesh, *, axis: str = "model"):
                 with registry.tp_scope(axis):
                     return step(params, cache, *rest)
 
-            return compat.shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(p_specs, c_specs) + (P(),) * n_rest,
                 out_specs=(P(), c_specs),

@@ -22,9 +22,9 @@ which is what makes tuned-vs-heuristic rows meaningful.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
-from ..core.model import TPU_V5E, HardwareSpec
+from ..core.model import HardwareSpec
 from ..core.plan import Level, TUNE_PREFETCH_DEPTHS
 from ..core.scaling import TilePlanner
 
@@ -51,7 +51,7 @@ def _divisors(n: int, cands: Sequence[int]) -> List[int]:
 
 
 def matmul_space(shape: Sequence[int], dtype_bytes: int = 4, *,
-                 hw: HardwareSpec = TPU_V5E,
+                 hw: Optional[HardwareSpec] = None,
                  max_candidates: int = MAX_CANDIDATES) -> List[PlanDict]:
     """shape = (m, k, n) for C[m,n] = A[m,k] @ B[k,n]."""
     m, k, n = shape
@@ -74,7 +74,7 @@ def matmul_space(shape: Sequence[int], dtype_bytes: int = 4, *,
 
 
 def quantized_matmul_space(shape: Sequence[int], dtype_bytes: int = 4, *,
-                           hw: HardwareSpec = TPU_V5E,
+                           hw: Optional[HardwareSpec] = None,
                            max_candidates: int = MAX_CANDIDATES
                            ) -> List[PlanDict]:
     """shape = (m, k, n) — the int8-weight matmul's own plan namespace.
@@ -88,7 +88,7 @@ def quantized_matmul_space(shape: Sequence[int], dtype_bytes: int = 4, *,
 
 
 def stencil_space(shape: Sequence[int], dtype_bytes: int = 4, *,
-                  hw: HardwareSpec = TPU_V5E,
+                  hw: Optional[HardwareSpec] = None,
                   max_candidates: int = MAX_CANDIDATES) -> List[PlanDict]:
     """shape = (rows, cols)."""
     rows, cols = shape
@@ -117,7 +117,7 @@ def stencil_space(shape: Sequence[int], dtype_bytes: int = 4, *,
 
 
 def attention_space(shape: Sequence[int], dtype_bytes: int = 2, *,
-                    hw: HardwareSpec = TPU_V5E,
+                    hw: Optional[HardwareSpec] = None,
                     max_candidates: int = MAX_CANDIDATES) -> List[PlanDict]:
     """shape = (batch, heads, seq, head_dim)."""
     _, _, s, hd = shape
@@ -149,7 +149,7 @@ def _attn_bwd_vmem(bq: int, bkv: int, hd: int, dtype_bytes: int) -> int:
 
 
 def flash_attention_bwd_space(shape: Sequence[int], dtype_bytes: int = 2, *,
-                              hw: HardwareSpec = TPU_V5E,
+                              hw: Optional[HardwareSpec] = None,
                               max_candidates: int = MAX_CANDIDATES
                               ) -> List[PlanDict]:
     """shape = (batch, heads, seq, head_dim) — same key as the forward.
@@ -176,7 +176,7 @@ def flash_attention_bwd_space(shape: Sequence[int], dtype_bytes: int = 2, *,
 
 
 def histogram_space(shape: Sequence[int], dtype_bytes: int = 4, *,
-                    hw: HardwareSpec = TPU_V5E,
+                    hw: Optional[HardwareSpec] = None,
                     max_candidates: int = MAX_CANDIDATES) -> List[PlanDict]:
     """shape = (n_values, n_bins)."""
     n, n_bins = shape
@@ -196,7 +196,7 @@ def histogram_space(shape: Sequence[int], dtype_bytes: int = 4, *,
 
 
 def nbody_space(shape: Sequence[int], dtype_bytes: int = 4, *,
-                hw: HardwareSpec = TPU_V5E,
+                hw: Optional[HardwareSpec] = None,
                 max_candidates: int = MAX_CANDIDATES) -> List[PlanDict]:
     """shape = (n_bodies,)."""
     (n,) = shape
@@ -227,7 +227,7 @@ def _decode_vmem(grp: int, ppt: int, page: int, hd: int, pf: int,
 
 
 def decode_attention_space(shape: Sequence[int], dtype_bytes: int = 2, *,
-                           hw: HardwareSpec = TPU_V5E,
+                           hw: Optional[HardwareSpec] = None,
                            max_candidates: int = MAX_CANDIDATES
                            ) -> List[PlanDict]:
     """shape = (slots, heads, n_pages, page_size, head_dim).
@@ -274,7 +274,7 @@ def _prefill_vmem(rows: int, ppt: int, page: int, hd: int, pf: int,
 
 
 def prefill_attention_space(shape: Sequence[int], dtype_bytes: int = 2, *,
-                            hw: HardwareSpec = TPU_V5E,
+                            hw: Optional[HardwareSpec] = None,
                             max_candidates: int = MAX_CANDIDATES
                             ) -> List[PlanDict]:
     """shape = (slots, chunk, heads, n_pages, page_size, head_dim).
@@ -323,7 +323,7 @@ SPACES = {
 
 # ------------------------------------------------------------- feasibility
 def plan_feasible(kernel: str, shape: Sequence[int], plan: PlanDict, *,
-                  dtype_bytes: int = 4, hw: HardwareSpec = TPU_V5E) -> bool:
+                  dtype_bytes: int = 4, hw: Optional[HardwareSpec] = None) -> bool:
     """Is a tuned plan dict VMEM-feasible for ``shape``?
 
     The single feasibility oracle behind the cache's nearest-shape lookup:

@@ -1,11 +1,8 @@
-"""Feature-detection branches of the JAX-version compat helpers.
+"""Mesh construction through ``launch/mesh.make_mesh``.
 
-``runtime/compat.shard_map`` and ``launch/mesh.make_mesh`` are the two
-mandated choke points for shard_map / mesh construction (lint-enforced).
-Their version branches were previously only exercised implicitly by
-whichever JAX the container pins; these tests drive BOTH sides of each
-feature detection directly on degenerate 1-device meshes, so an upgrade
-that flips a branch fails here instead of deep inside serving.
+``make_mesh`` is the one place meshes are built (lint-enforced); these
+tests pin its contract on degenerate 1-device meshes: Auto axis types are
+always requested, and a mapped region over such a mesh round-trips values.
 """
 import jax
 import jax.numpy as jnp
@@ -14,57 +11,28 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.launch import mesh as mesh_mod
-from repro.runtime import compat
 
 
 # ------------------------------------------------------------- shard_map
 
 def test_shard_map_1device_runs():
-    """Whichever branch the installed JAX selects, a degenerate 1-device
-    mapped identity round-trips values exactly."""
+    """A degenerate 1-device mapped identity round-trips values exactly."""
     m = mesh_mod.make_mesh((1,), ("model",))
     x = jnp.arange(12.0).reshape(3, 4)
-    out = compat.shard_map(lambda t: t * 2.0, mesh=m, in_specs=(P(),),
-                           out_specs=P())(x)
+    out = jax.shard_map(lambda t: t * 2.0, mesh=m, in_specs=(P(),),
+                        out_specs=P())(x)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 2.0)
 
 
 def test_shard_map_check_vma_kwarg_both_values():
-    """check_vma must be accepted on both branches (mapped to check_rep on
-    0.4.x); False is what sharded serving uses for collective outputs."""
+    """check_vma=False is what sharded serving uses for collective
+    outputs; both values run on a make_mesh mesh."""
     m = mesh_mod.make_mesh((1,), ("model",))
     x = jnp.ones((2, 2))
     for flag in (True, False):
-        out = compat.shard_map(lambda t: t + 1.0, mesh=m, in_specs=(P(),),
-                               out_specs=P(), check_vma=flag)(x)
+        out = jax.shard_map(lambda t: t + 1.0, mesh=m, in_specs=(P(),),
+                            out_specs=P(), check_vma=flag)(x)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(x) + 1.0)
-
-
-def test_shard_map_toplevel_branch(monkeypatch):
-    """When jax.shard_map exists, compat must use it and pass check_vma."""
-    seen = {}
-
-    def fake(f, *, mesh, in_specs, out_specs, check_vma):
-        seen.update(mesh=mesh, check_vma=check_vma)
-        return f
-
-    monkeypatch.setattr(jax, "shard_map", fake, raising=False)
-    m = mesh_mod.make_mesh((1,), ("model",))
-    f = compat.shard_map(lambda t: t, mesh=m, in_specs=(P(),),
-                         out_specs=P(), check_vma=False)
-    assert seen == {"mesh": m, "check_vma": False}
-    assert f(3) == 3
-
-
-def test_shard_map_experimental_branch(monkeypatch):
-    """Without jax.shard_map, compat falls back to the experimental API
-    (check_vma renamed check_rep) — the live branch on the pinned 0.4.x."""
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    m = mesh_mod.make_mesh((1,), ("model",))
-    x = jnp.arange(4.0)
-    out = compat.shard_map(lambda t: t * 3.0, mesh=m, in_specs=(P(),),
-                          out_specs=P(), check_vma=False)(x)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 3.0)
 
 
 # ------------------------------------------------------------- make_mesh
@@ -73,49 +41,25 @@ def test_make_mesh_1device():
     m = mesh_mod.make_mesh((1,), ("model",))
     assert m.shape == {"model": 1}
     assert m.axis_names == ("model",)
+    assert m.axis_types == (jax.sharding.AxisType.Auto,)
 
 
 def test_make_mesh_axis_types_branch(monkeypatch):
-    """Force the axis_types-supported branch and check the kwarg flows."""
+    """The axis_types kwarg (one Auto per axis) and devices flow through
+    to ``jax.make_mesh``."""
     seen = {}
 
     def fake_make_mesh(shape, axes, **kwargs):
         seen.update(shape=shape, axes=axes, kwargs=kwargs)
         return "mesh-sentinel"
 
-    monkeypatch.setattr(mesh_mod, "_axis_types_supported", lambda: True)
     monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-    if not hasattr(jax.sharding, "AxisType"):
-        # pinned 0.4.x has no AxisType: fake one so the forced branch can
-        # build its tuple (newer JAX exercises the real enum)
-        class _FakeAxisType:
-            Auto = "auto"
-        monkeypatch.setattr(jax.sharding, "AxisType", _FakeAxisType,
-                            raising=False)
-    assert mesh_mod.make_mesh((1,), ("model",)) == "mesh-sentinel"
+    devs = jax.devices()[:1]
+    assert mesh_mod.make_mesh((1,), ("model",),
+                              devices=devs) == "mesh-sentinel"
     assert seen["shape"] == (1,) and seen["axes"] == ("model",)
-    assert "axis_types" in seen["kwargs"]
-    assert len(seen["kwargs"]["axis_types"]) == 1
-
-
-def test_make_mesh_no_axis_types_branch(monkeypatch):
-    """Force the legacy branch: the kwarg must be omitted entirely."""
-    seen = {}
-
-    def fake_make_mesh(shape, axes, **kwargs):
-        seen.update(kwargs=kwargs)
-        return "mesh-sentinel"
-
-    monkeypatch.setattr(mesh_mod, "_axis_types_supported", lambda: False)
-    monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-    assert mesh_mod.make_mesh((1,), ("model",)) == "mesh-sentinel"
-    assert "axis_types" not in seen["kwargs"]
-
-
-def test_axis_types_detection_is_bool():
-    # the real detection must run (lru_cached) and return a plain bool —
-    # never a version-string comparison artifact
-    assert isinstance(mesh_mod._axis_types_supported(), bool)
+    assert seen["kwargs"]["axis_types"] == (jax.sharding.AxisType.Auto,)
+    assert seen["kwargs"]["devices"] == devs
 
 
 # ------------------------------------------------------ make_serving_mesh

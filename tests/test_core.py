@@ -128,6 +128,29 @@ def test_tileplanner_prefers_reuse():
     assert plan.arithmetic_intensity >= small.arithmetic_intensity
 
 
+@pytest.mark.parametrize("shape", [(8, 3360, 4096), (8, 4096, 3360),
+                                   (320, 4096, 4096)])
+def test_tileplanner_whole_dim_block_for_unaligned_dims(shape):
+    """A dim no MXU-aligned tile divides (a tp=4 shard of d_ff 13440 is
+    3360) gets one whole-dim block; aligned dims keep aligned tiles."""
+    m, n, k = shape
+    plan = TilePlanner().plan_matmul(m, n, k)
+    for b, dim in zip((plan.bm, plan.bn, plan.bk), shape):
+        assert not dim % min(b, dim)
+        assert b % 128 == 0 or b == dim
+
+
+def test_matmul_kernel_on_whole_dim_block():
+    from repro.kernels import dispatch
+    x = jax.random.normal(jax.random.key(0), (8, 64), jnp.float32)
+    w = jax.random.normal(jax.random.key(1), (64, 336), jnp.float32)
+    assert TilePlanner().plan_matmul(8, 336, 64).bn == 336
+    with dispatch.stats_scope() as stats:
+        got = dispatch.matmul(x, w, policy="kernels")
+        assert stats() == {("matmul", "kernel"): 1}
+    np.testing.assert_allclose(got, x @ w, rtol=1e-5, atol=1e-4)
+
+
 def test_vector_pad_and_lane_utilization():
     assert vector_pad((100,), 4) == (128,)
     assert vector_pad((5, 100), 4) == (8, 128)

@@ -54,9 +54,9 @@ def _paged_inputs(n_heads, n_kv_heads, hd, dtype, *, slots=3, page=8,
     ks = jax.random.split(jax.random.key(0), 3)
     q = (0.5 * jax.random.normal(ks[0], (slots, n_heads, hd),
                                  jnp.float32)).astype(dtype)
-    kp = (0.5 * jax.random.normal(ks[1], (pool, page, n_kv_heads, hd),
+    kp = (0.5 * jax.random.normal(ks[1], (pool, n_kv_heads, page, hd),
                                   jnp.float32)).astype(dtype)
-    vp = (0.5 * jax.random.normal(ks[2], (pool, page, n_kv_heads, hd),
+    vp = (0.5 * jax.random.normal(ks[2], (pool, n_kv_heads, page, hd),
                                   jnp.float32)).astype(dtype)
     rng = np.random.default_rng(0)
     table = jnp.asarray(
@@ -197,11 +197,11 @@ def test_decode_tuned_plan_consumed(tmp_path, monkeypatch):
     (lookup counters prove the cache was consulted)."""
     monkeypatch.setenv("REPRO_TUNE_CACHE", str(tmp_path / "plans.json"))
     q, kp, vp, table = _paged_inputs(4, 2, 16, jnp.float32)
-    shape = (q.shape[0], q.shape[1], table.shape[1], kp.shape[1],
+    shape = (q.shape[0], q.shape[1], table.shape[1], kp.shape[2],
              q.shape[2])
     cache = tune_cache.PlanCache(tmp_path / "plans.json")
     cache.put("decode_attention", shape, jnp.float32,
-              {"level": 3, "page_size": kp.shape[1], "pages_per_tile": 2,
+              {"level": 3, "page_size": kp.shape[2], "pages_per_tile": 2,
                "prefetch_depth": 2}, us=1.0)
     cache.save()
     tune_cache.preload()

@@ -49,9 +49,9 @@ def _prefill_inputs(n_heads, n_kv_heads, hd, dtype, *, slots=3, chunk=8,
     ks = jax.random.split(jax.random.key(0), 3)
     q = (0.5 * jax.random.normal(ks[0], (slots, chunk, n_heads, hd),
                                  jnp.float32)).astype(dtype)
-    kp = (0.5 * jax.random.normal(ks[1], (pool, page, n_kv_heads, hd),
+    kp = (0.5 * jax.random.normal(ks[1], (pool, n_kv_heads, page, hd),
                                   jnp.float32)).astype(dtype)
-    vp = (0.5 * jax.random.normal(ks[2], (pool, page, n_kv_heads, hd),
+    vp = (0.5 * jax.random.normal(ks[2], (pool, n_kv_heads, page, hd),
                                   jnp.float32)).astype(dtype)
     rng = np.random.default_rng(0)
     table = jnp.asarray(
@@ -154,9 +154,9 @@ def test_prefill_first_chunk_matches_pure_causal_attention():
     ks = jax.random.split(jax.random.key(3), 3)
     q, k, v = (0.5 * jax.random.normal(kk, (1, chunk, h, hd), jnp.float32)
                for kk in ks)
-    pool = jnp.zeros((3, page, h, hd), jnp.float32)
-    kp = pool.at[1].set(k[0])
-    vp = pool.at[1].set(v[0])
+    pool = jnp.zeros((3, h, page, hd), jnp.float32)
+    kp = pool.at[1].set(k[0].transpose(1, 0, 2))
+    vp = pool.at[1].set(v[0].transpose(1, 0, 2))
     table = jnp.asarray([[1, 0]], jnp.int32)
     out = dispatch.prefill_attention(q, kp, vp, table,
                                      jnp.asarray([0], jnp.int32),
@@ -178,8 +178,8 @@ def test_prefill_padded_tail_hidden_by_causality():
     # trash everything at positions > the chunk's last real token: the
     # pages beyond the chunk's own page (there are none here) and nothing
     # else — instead, poison a *later* logical page mapped by the table
-    kp2 = kp.at[table[0, 1], 4:].set(1e3)   # positions 12.. of the chunk
-    vp2 = vp.at[table[0, 1], 4:].set(1e3)
+    kp2 = kp.at[table[0, 1], :, 4:].set(1e3)   # chunk positions 12..
+    vp2 = vp.at[table[0, 1], :, 4:].set(1e3)
     got = dispatch.prefill_attention(q, kp2, vp2, table, starts,
                                      policy="kernels")
     # rows 0..3 (positions 8..11) never see positions 12..15
@@ -192,10 +192,10 @@ def test_prefill_tuned_plan_consumed(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_TUNE_CACHE", str(tmp_path / "plans.json"))
     q, kp, vp, table, starts = _prefill_inputs(4, 2, 16, jnp.float32)
     shape = (q.shape[0], q.shape[1], q.shape[2], table.shape[1],
-             kp.shape[1], q.shape[3])
+             kp.shape[2], q.shape[3])
     cache = tune_cache.PlanCache(tmp_path / "plans.json")
     cache.put("prefill_attention", shape, jnp.float32,
-              {"level": 3, "page_size": kp.shape[1], "pages_per_tile": 2,
+              {"level": 3, "page_size": kp.shape[2], "pages_per_tile": 2,
                "prefetch_depth": 2}, us=1.0)
     cache.save()
     tune_cache.preload()

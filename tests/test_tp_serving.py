@@ -126,7 +126,7 @@ def test_pspec_derivation():
     cgroup = next(g for g in ("stack", "prefix", "tail") if cache[g])
     clayer = cspecs[cgroup][0]
     clead = 1 if cgroup == "stack" else 0
-    assert axis_of(clayer["k_pages"]) == clead + 2       # (P,page,Hkv,hd)
+    assert axis_of(clayer["k_pages"]) == clead + 1       # (P,Hkv,page,hd)
     # MQA: everything KV replicates
     gemma = _make_model()
     gcache = jax.eval_shape(lambda: gemma.init_paged_cache(2, 64, 16))
