@@ -34,6 +34,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .loadgen import ArrivalQueue, Request
 from .metrics import ServeMetrics
 from .speculative import accept_longest_prefix
@@ -123,13 +124,15 @@ class BatchPolicy:
 class StepExecutor:
     """Issues a composed :class:`StepPlan` through the scheduler's jitted
     paged forwards, accumulating per-phase wall time and the multi-slot
-    batch-width stats the acceptance probes read."""
+    batch-width stats the acceptance probes read.  ``t_prefill`` and
+    ``t_decode`` are the seconds of each step's ``launch`` and ``wait``
+    spans (``launch/tracing.py``): from the first host-to-device copy to
+    the blocking read-back."""
 
     def __init__(self, sched):
         self.sched = sched
         self.t_prefill = 0.0
         self.t_decode = 0.0
-        self.prefill_calls = 0
         self.prefill_chunks = 0
         self.max_prefill_batch = 0
 
@@ -139,18 +142,23 @@ class StepExecutor:
         Returns (B, V) logits; row i is chunk i's last real position."""
         sched = self.sched
         page = sched.page
-        toks = np.stack([states[s].toks[st:st + page] for s, st in chunks])
-        starts = np.asarray([st for _, st in chunks], np.int32)
-        tables = sched.table[[s for s, _ in chunks]]
-        last = np.asarray([min(states[s].ln, st + page) - 1 - st
-                           for s, st in chunks], np.int32)
-        t0 = time.perf_counter()
-        logits, sched.cache = sched._prefill(
-            sched.params, sched.cache, jnp.asarray(toks),
-            jnp.asarray(starts), jnp.asarray(tables), jnp.asarray(last))
-        logits = np.asarray(logits)
-        self.t_prefill += time.perf_counter() - t0
-        self.prefill_calls += 1
+        with tracing.span("prefill") as sp:
+            tracing.count("prefill_chunks", len(chunks))
+            with tracing.span("prefill.prepare"):
+                toks = np.stack([states[s].toks[st:st + page]
+                                 for s, st in chunks])
+                starts = np.asarray([st for _, st in chunks], np.int32)
+                tables = sched.table[[s for s, _ in chunks]]
+                last = np.asarray([min(states[s].ln, st + page) - 1 - st
+                                   for s, st in chunks], np.int32)
+            with tracing.span("prefill.launch"):
+                logits, sched.cache = sched._prefill(
+                    sched.params, sched.cache, tracing.to_device(toks),
+                    tracing.to_device(starts), tracing.to_device(tables),
+                    tracing.to_device(last))
+            with tracing.span("prefill.wait"):
+                logits = tracing.to_host(logits)
+        self.t_prefill += sp.ns_of("prefill.launch", "prefill.wait") * 1e-9
         self.prefill_chunks += len(chunks)
         self.max_prefill_batch = max(self.max_prefill_batch, len(chunks))
         return logits
@@ -160,14 +168,17 @@ class StepExecutor:
         idle) ride along with a zero length and an all-trash table view,
         so their masked writes can never touch a live page."""
         sched = self.sched
-        sched.prepare_decode(decode_slots)   # copy-on-write sweep first
-        mask = np.zeros((sched.slots,), bool)
-        mask[decode_slots] = True
-        lengths = np.where(mask, sched.lengths, 0).astype(np.int32)
-        table = np.where(mask[:, None], sched.table, 0).astype(np.int32)
-        t0 = time.perf_counter()
-        nxt = sched.step(cur, view=(lengths, table))
-        self.t_decode += time.perf_counter() - t0
+        with tracing.span("decode") as sp:
+            tracing.count("decode_rows", len(decode_slots))
+            with tracing.span("decode.prepare"):
+                sched.prepare_decode(decode_slots)   # copy-on-write sweep
+                mask = np.zeros((sched.slots,), bool)
+                mask[decode_slots] = True
+                lengths = np.where(mask, sched.lengths, 0).astype(np.int32)
+                table = np.where(mask[:, None], sched.table,
+                                 0).astype(np.int32)
+            nxt = sched.step(cur, view=(lengths, table))
+        self.t_decode += sp.ns_of("decode.launch", "decode.wait") * 1e-9
         return nxt
 
     def verify(self, cur: np.ndarray, decode_slots: List[int],
@@ -178,19 +189,22 @@ class StepExecutor:
         trash page exactly as in :meth:`decode`.  Returns (slots, width)
         greedy predictions."""
         sched = self.sched
-        sched.prepare_verify(decode_slots, width)  # full-span CoW sweep
-        toks = np.zeros((sched.slots, width), np.int32)
-        mask = np.zeros((sched.slots,), bool)
-        for slot in decode_slots:
-            mask[slot] = True
-            toks[slot, 0] = cur[slot]
-            ks = drafts.get(slot, [])
-            toks[slot, 1:1 + len(ks)] = ks
-        lengths = np.where(mask, sched.lengths, 0).astype(np.int32)
-        table = np.where(mask[:, None], sched.table, 0).astype(np.int32)
-        t0 = time.perf_counter()
-        preds = sched.verify_step(toks, view=(lengths, table))
-        self.t_decode += time.perf_counter() - t0
+        with tracing.span("verify") as sp:
+            tracing.count("decode_rows", len(decode_slots))
+            with tracing.span("verify.prepare"):
+                sched.prepare_verify(decode_slots, width)  # full-span CoW
+                toks = np.zeros((sched.slots, width), np.int32)
+                mask = np.zeros((sched.slots,), bool)
+                for slot in decode_slots:
+                    mask[slot] = True
+                    toks[slot, 0] = cur[slot]
+                    ks = drafts.get(slot, [])
+                    toks[slot, 1:1 + len(ks)] = ks
+                lengths = np.where(mask, sched.lengths, 0).astype(np.int32)
+                table = np.where(mask[:, None], sched.table,
+                                 0).astype(np.int32)
+            preds = sched.verify_step(toks, view=(lengths, table))
+        self.t_decode += sp.ns_of("verify.launch", "verify.wait") * 1e-9
         return preds
 
 
@@ -237,6 +251,8 @@ class ContinuousEngine:
         # that stays comparable across kv_dtype, unlike max_resident
         # (request count) or held pages (dtype-blind)
         self.max_resident_kv_bytes = 0
+        self.trace_id = tracing.new_engine_id()
+        self.step_calls = 0
 
     # ------------------------------------------------------------- warmup
     def warmup(self) -> None:
@@ -333,27 +349,37 @@ class ContinuousEngine:
 
     # ------------------------------------------------------ one iteration
     def step(self) -> bool:
-        """One engine iteration; returns False once fully drained."""
+        """One engine iteration; returns False once fully drained.  Each
+        call leaves one record in ``launch/tracing.py``."""
+        with tracing.iteration(self.trace_id, self.step_calls):
+            self.step_calls += 1
+            return self._step()
+
+    def _step(self) -> bool:
         sched = self.sched
         now = self.clock
-        if self.queue is not None:
-            for r in self.queue.pop_ready(now):
-                self.metrics.on_arrival(r.rid, r.arrival)
-                self.waiting.append(r)
-        self._admit(now)
-        self.max_resident = max(
-            self.max_resident,
-            sum(1 for a in sched.active if a is not None))
-        self.max_resident_kv_bytes = max(
-            self.max_resident_kv_bytes, sched.kv_bytes_resident())
+        with tracing.span("engine.admit"):
+            if self.queue is not None:
+                for r in self.queue.pop_ready(now):
+                    self.metrics.on_arrival(r.rid, r.arrival)
+                    self.waiting.append(r)
+            self._admit(now)
+            self.max_resident = max(
+                self.max_resident,
+                sum(1 for a in sched.active if a is not None))
+            self.max_resident_kv_bytes = max(
+                self.max_resident_kv_bytes, sched.kv_bytes_resident())
 
-        running = [i for i in range(sched.slots)
-                   if sched.active[i] is not None and self.states[i] is None]
-        prefilling = [(i, self.states[i].pos) for i in range(sched.slots)
-                      if self.states[i] is not None]
-        drafts = (sched.draft_for(self.drafter, running)
-                  if self.drafter is not None and running else None)
-        plan = self.policy.compose(running, prefilling, drafts=drafts)
+        with tracing.span("engine.compose"):
+            running = [i for i in range(sched.slots)
+                       if sched.active[i] is not None
+                       and self.states[i] is None]
+            prefilling = [(i, self.states[i].pos)
+                          for i in range(sched.slots)
+                          if self.states[i] is not None]
+            drafts = (sched.draft_for(self.drafter, running)
+                      if self.drafter is not None and running else None)
+            plan = self.policy.compose(running, prefilling, drafts=drafts)
 
         if plan.empty():
             nxt = (self.queue.next_arrival()
@@ -373,19 +399,24 @@ class ContinuousEngine:
         t0 = time.perf_counter()
         logits = (self.executor.prefill(plan.prefill, self.states)
                   if plan.prefill else None)
-        speculative = self.drafter is not None
         nxt_tok = preds = None
         if plan.decode:
-            if speculative:
+            if self.drafter is not None:
                 preds = self.executor.verify(self.cur, plan.decode,
                                              plan.verify, self.verify_width)
             else:
                 nxt_tok = self.executor.decode(self.cur, plan.decode)
-        self.clock += ((time.perf_counter() - t0)
-                       if self.clock_mode == "wall" else self.tick)
-        self.iterations += 1
-        t = self.clock
+        with tracing.span("engine.account"):
+            self.clock += ((time.perf_counter() - t0)
+                           if self.clock_mode == "wall" else self.tick)
+            self.iterations += 1
+            self._account(plan, logits, nxt_tok, preds)
+        return True
 
+    def _account(self, plan: StepPlan, logits, nxt_tok, preds) -> None:
+        """Emit the step's tokens; finish, truncate or reclaim slots."""
+        sched = self.sched
+        t = self.clock
         for row, (slot, _start) in enumerate(plan.prefill):
             st = self.states[slot]
             st.pos += sched.page
@@ -410,7 +441,7 @@ class ContinuousEngine:
 
         for slot in plan.decode:
             r = sched.active[slot]
-            if speculative:
+            if self.drafter is not None:
                 # longest-correct-prefix acceptance + host rollback: the
                 # emission loop replicates the plain decode path's
                 # per-token finish checks exactly, so greedy streams
@@ -450,7 +481,6 @@ class ContinuousEngine:
                 self._finish(slot, t)
             else:
                 sched._reclaim_slot(slot)
-        return True
 
     # ---------------------------------------------------------------- run
     def submit(self, requests: List[Request]) -> None:

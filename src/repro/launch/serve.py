@@ -47,6 +47,7 @@ import numpy as np
 from ..configs import get_arch
 from ..core.memory import DtypePolicy
 from ..models.transformer import ExecOptions, Model, paged_supported
+from . import tracing
 from .loadgen import Request  # noqa: F401  (re-export: the historical home)
 from .prefix import PrefixCache
 
@@ -484,16 +485,17 @@ class PagedScheduler:
         padded = -(-ln // self.page) * self.page
         toks = np.zeros((padded,), np.int32)
         toks[:ln] = r.prompt
-        table_row = jnp.asarray(self.table[slot])
+        table_row = tracing.to_device(self.table[slot])
         logits = None
         for t0 in range(start, ln, self.page):
             last = min(ln, t0 + self.page) - 1 - t0
             logits, self.cache = self._prefill(
                 self.params, self.cache,
-                jnp.asarray(toks[t0:t0 + self.page])[None],
-                jnp.int32(t0), table_row, jnp.int32(last))
+                tracing.to_device(toks[t0:t0 + self.page])[None],
+                tracing.to_device(np.int32(t0)), table_row,
+                tracing.to_device(np.int32(last)))
         self.prefill_tokens += ln - start
-        return int(np.argmax(np.asarray(logits[0])))
+        return int(np.argmax(tracing.to_host(logits[0])))
 
     def _first_token_via_decode(self, slot: int, token: int) -> int:
         """One masked ragged decode advancing only ``slot`` (other slots'
@@ -537,8 +539,9 @@ class PagedScheduler:
             if need > 0 and self.prefix is not None:
                 self.prefix.evict(need, self.alloc)
             dst = self.alloc.alloc(1)[0]
-        self.cache = self._copy_page(self.cache, jnp.int32(src),
-                                     jnp.int32(dst))
+        self.cache = self._copy_page(self.cache,
+                                     tracing.to_device(np.int32(src)),
+                                     tracing.to_device(np.int32(dst)))
         self.slot_pages[slot][idx] = dst
         self.table[slot, idx] = dst
         self.alloc.release([src])
@@ -606,7 +609,7 @@ class PagedScheduler:
         """Allocator ``on_alloc`` hook: zero the scale rows of every page
         the allocator just handed out (see ``_reset_page_scales``)."""
         self.cache = _reset_page_scales(
-            self.cache, jnp.asarray(pages, jnp.int32))
+            self.cache, tracing.to_device(np.asarray(pages, np.int32)))
 
     def held_pages(self) -> int:
         """Physical pages with at least one holder (excl. trash page 0).
@@ -698,10 +701,10 @@ class PagedScheduler:
     # --------------------------------------------------------------- decode
     def _feed_batch(self, tokens: np.ndarray,
                     lengths: np.ndarray) -> Dict[str, jax.Array]:
-        batch = {"tokens": jnp.asarray(tokens)[:, None]}
+        batch = {"tokens": tracing.to_device(tokens)[:, None]}
         if self.model.cfg.mrope_sections:
             batch["positions"] = jnp.broadcast_to(
-                jnp.asarray(lengths)[:, None, None],
+                tracing.to_device(lengths)[:, None, None],
                 (self.slots, 1, len(self.model.cfg.mrope_sections))
             ).astype(jnp.int32)
         return batch
@@ -716,13 +719,16 @@ class PagedScheduler:
         """
         lengths, table = view if view is not None \
             else (self.lengths, self.table)
-        logits, self.cache = self._decode(
-            self.params, self.cache, self._feed_batch(tokens, lengths),
-            jnp.int32(0),
-            (jnp.asarray(lengths), jnp.asarray(table)))
-        self.decode_steps += 1
-        self.decode_tokens += int(np.count_nonzero(lengths))
-        return np.asarray(jnp.argmax(logits, axis=-1))
+        with tracing.span("decode.launch"):
+            logits, self.cache = self._decode(
+                self.params, self.cache, self._feed_batch(tokens, lengths),
+                tracing.to_device(np.int32(0)),
+                (tracing.to_device(lengths), tracing.to_device(table)))
+            self.decode_steps += 1
+            self.decode_tokens += int(np.count_nonzero(lengths))
+            nxt = jnp.argmax(logits, axis=-1)
+        with tracing.span("decode.wait"):
+            return tracing.to_host(nxt)
 
     # --------------------------------------------------- speculative decoding
     def draft_for(self, drafter, slots: List[int]) -> Dict[int, List[int]]:
@@ -765,11 +771,14 @@ class PagedScheduler:
                 "run unsharded or drop --speculate")
         lengths, table = view if view is not None \
             else (self.lengths, self.table)
-        logits, self.cache = self._verify(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(lengths), jnp.asarray(table))
-        self.verify_steps += 1
-        return np.asarray(jnp.argmax(logits, axis=-1))
+        with tracing.span("verify.launch"):
+            logits, self.cache = self._verify(
+                self.params, self.cache, tracing.to_device(tokens),
+                tracing.to_device(lengths), tracing.to_device(table))
+            self.verify_steps += 1
+            preds = jnp.argmax(logits, axis=-1)
+        with tracing.span("verify.wait"):
+            return tracing.to_host(preds)
 
     def note_spec(self, drafted: int, accepted: int, emitted: int) -> None:
         self.spec_drafted += drafted
@@ -1100,20 +1109,22 @@ def main(argv=None):
         # the routes the run then executes from cache
         dispatch.reset_stats()
         engine.warmup()
+        spans_before = tracing.totals()
         t0 = time.time()
         done = engine.run(reqs)
         dt = time.time() - t0
         s = engine.metrics.summary()
-        ex = engine.executor
         total_new = sum(len(r.out) for r in done)
         print(f"served {len(done)} requests, {total_new} new tokens "
               f"in {dt:.2f}s ({total_new/dt:.1f} tok/s, {args.slots} "
               f"slots, schedule=continuous, "
               f"budget={engine.policy.token_budget})")
         print(f"[engine] iterations={engine.iterations} "
-              f"prefill_calls={ex.prefill_calls} "
-              f"max_prefill_batch={ex.max_prefill_batch} "
+              f"max_prefill_batch={engine.executor.max_prefill_batch} "
               f"rejected={server.rejected}")
+        print("[spans] mean ms (calls): " + " ".join(
+            f"{k}={t.ns / t.calls * 1e-6:.3f}({t.calls})"
+            for k, t in tracing.since(spans_before).items()))
         fmt = lambda v: "n/a" if v is None else f"{v:.4f}"
         print(f"[engine] ttft p50={fmt(s['ttft_p50'])} "
               f"p99={fmt(s['ttft_p99'])}  tok_latency "
