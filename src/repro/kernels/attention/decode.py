@@ -22,10 +22,13 @@ shared pool.  This is the paper's memory stack applied to a cache:
 Layout: q (B, H, hd) — one token per slot, GQA-grouped to (B, Hkv, grp,
 hd); k_pages / v_pages (P, Hkv, page, hd) — head-major, so one page of
 one kv head is a contiguous (page, hd) tile whose BlockSpec (1, 1, page,
-hd) satisfies the TPU's (8, 128) tiling on its two minor dims; table (B,
-n_pages) int32 page ids (row j is the slot's j-th logical page); lengths
-(B,) int32 valid tokens per slot (0 = inactive slot -> zero output, no
-NaNs).
+hd) satisfies the TPU's (8, 128) tiling on its two minor dims — or the
+model's layer-stacked (L, P, Hkv, page, hd) pool plus a ``layer`` index,
+which rides scalar prefetch and picks the layer in the page index map, so
+the kernel reads one layer's pages straight out of the stack and no
+per-layer copy of the pool is ever made; table (B, n_pages) int32 page ids
+(row j is the slot's j-th logical page); lengths (B,) int32 valid tokens
+per slot (0 = inactive slot -> zero output, no NaNs).
 """
 from __future__ import annotations
 
@@ -46,9 +49,10 @@ def heuristic_pages_per_tile(n_pages: int, page_size: int) -> int:
     return max(1, min(n_pages, 512 // max(page_size, 1), 8))
 
 
-def _decode_kernel(lengths_ref, table_ref, *rest, n_tiles: int,
+def _decode_kernel(lengths_ref, table_ref, layer_ref, *rest, n_tiles: int,
                    page: int, ppt: int, window: int, scale: float,
                    quantized: bool):
+    del layer_ref                     # read by the page index maps only
     if quantized:
         k_scale_ref, v_scale_ref, q_ref, *refs = rest
     else:
@@ -118,18 +122,23 @@ def decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, table: jax.Array,
                             lengths: jax.Array,
                             k_scale: jax.Array = None,
-                            v_scale: jax.Array = None, *, window: int = 0,
-                            pages_per_tile: int = 1,
+                            v_scale: jax.Array = None, *, layer=None,
+                            window: int = 0, pages_per_tile: int = 1,
                             interpret: bool = False) -> jax.Array:
-    """q (B, H, hd); k/v_pages (P, Hkv, page, hd); table (B, n_pages);
-    lengths (B,).  Returns (B, H, hd) f32.
+    """q (B, H, hd); k/v_pages (P, Hkv, page, hd), or (L, P, Hkv, page,
+    hd) with the scalar ``layer`` to read; table (B, n_pages); lengths
+    (B,).  Returns (B, H, hd) f32.
 
     int8 pools additionally take ``k_scale`` / ``v_scale`` (P, Hkv) f32
-    per-page per-kv-head scales; they ride the scalar-prefetch path next
-    to ``table`` and the page tiles dequantize at load time."""
+    per-page per-kv-head scales (one layer's, also for a stacked pool);
+    they ride the scalar-prefetch path next to ``table`` and the page
+    tiles dequantize at load time."""
     quantized = k_scale is not None
+    if k_pages.ndim == 4:
+        # an unstacked pool is a stack of one layer (a free reshape)
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     b, h, hd = q.shape
-    _, hkv, page, _ = k_pages.shape
+    _, _, hkv, page, _ = k_pages.shape
     n_pages = table.shape[1]
     assert h % hkv == 0, (h, hkv)
     grp = h // hkv
@@ -148,20 +157,21 @@ def decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
         window=window, scale=1.0 / math.sqrt(hd), quantized=quantized)
 
     # int8 pools prefetch two extra scalar operands (the scale tables), so
-    # every index map takes a *prefetch tail of 2 or 4 refs
+    # every index map takes a *prefetch tail of 0 or 2 refs
     def page_spec(i):
         # the i-th page stream of a KV tile: tile j holds logical pages
         # [j*ppt, (j+1)*ppt); the scalar-prefetched table resolves the
-        # logical -> physical page id inside the index map (§4.1)
+        # logical -> physical page id inside the index map (§4.1), and the
+        # prefetched layer index the layer of the stack
         return pl.BlockSpec(
-            (1, 1, page, hd),
-            lambda bb, hh, jj, lens, tab, *_sc, i=i: (tab[bb, jj * ppt + i],
-                                                      hh, 0, 0))
+            (None, 1, 1, page, hd),
+            lambda bb, hh, jj, lens, tab, lyr, *_sc, i=i: (
+                lyr[0], tab[bb, jj * ppt + i], hh, 0, 0))
 
     q_spec = pl.BlockSpec((1, 1, grp, hd),
-                          lambda bb, hh, jj, lens, tab, *_sc: (bb, hh, 0, 0))
+                          lambda bb, hh, jj, *_: (bb, hh, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if quantized else 2,
+        num_scalar_prefetch=5 if quantized else 3,
         grid=(b, hkv, n_tiles),
         in_specs=[
             q_spec,
@@ -169,15 +179,14 @@ def decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
             *[page_spec(i) for i in range(ppt)],
         ],
         out_specs=pl.BlockSpec((1, 1, grp, hd),
-                               lambda bb, hh, jj, lens, tab, *_sc:
-                               (bb, hh, 0, 0)),
+                               lambda bb, hh, jj, *_: (bb, hh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((grp, 1), jnp.float32),     # running max
             pltpu.VMEM((grp, 1), jnp.float32),     # running denom
             pltpu.VMEM((grp, hd), jnp.float32),    # weighted-V acc
         ],
     )
-    prefetch = (lengths, table)
+    prefetch = (lengths, table, jnp.reshape(layer, (1,)).astype(jnp.int32))
     if quantized:
         prefetch += (k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32))

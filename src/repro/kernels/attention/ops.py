@@ -143,13 +143,15 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
 @functools.partial(jax.jit, static_argnames=("window", "level",
                                              "pages_per_tile", "interpret"))
 def _decode_attention(q, k_pages, v_pages, table, lengths, k_scale,
-                      v_scale, *, window: int, level: Level,
+                      v_scale, layer, *, window: int, level: Level,
                       pages_per_tile: int, interpret: bool) -> jax.Array:
     if level in (Level.T0_NAIVE, Level.T1_PIPELINED):
         return ref.decode_attention_ref(q, k_pages, v_pages, table, lengths,
-                                        k_scale, v_scale, window=window)
+                                        k_scale, v_scale, window=window,
+                                        layer=layer)
     return decode_attention_pallas(q, k_pages, v_pages, table, lengths,
-                                   k_scale, v_scale, window=window,
+                                   k_scale, v_scale, layer=layer,
+                                   window=window,
                                    pages_per_tile=pages_per_tile,
                                    interpret=interpret)
 
@@ -158,6 +160,7 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      table: jax.Array, lengths: jax.Array,
                      k_scale: Optional[jax.Array] = None,
                      v_scale: Optional[jax.Array] = None, *,
+                     layer: Optional[jax.Array] = None,
                      window: int = 0,
                      level: Level = Level.T3_REPLICATED,
                      pages_per_tile: Optional[int] = None,
@@ -166,10 +169,12 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Ragged decode attention over a paged KV cache.
 
     q (B, H, hd) — one query token per slot; k_pages / v_pages (P, Hkv,
-    page, hd) shared page pools; table (B, n_pages) int32 logical->physical
-    page ids; lengths (B,) int32 valid tokens per slot (0 = inactive slot,
-    output 0).  int8 pools additionally take ``k_scale`` / ``v_scale``
-    (P, Hkv) f32 per-page per-kv-head scales (in-kernel dequant, §4.4).
+    page, hd) shared page pools, or the model's layer-stacked (L, P, Hkv,
+    page, hd) pools read at the scalar ``layer``; table (B, n_pages) int32
+    logical->physical page ids; lengths (B,) int32 valid tokens per slot
+    (0 = inactive slot, output 0).  int8 pools additionally take
+    ``k_scale`` / ``v_scale`` (P, Hkv) f32 per-page per-kv-head scales of
+    the layer read (in-kernel dequant, §4.4).
     Returns (B, H, hd) f32.  T0/T1 gather pages to a dense masked
     reference; T2+ run the scalar-prefetch Pallas kernel.
 
@@ -184,7 +189,7 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if interpret is None:
         interpret = interpret_default()
     b, h, hd = q.shape
-    page = k_pages.shape[2]
+    page = k_pages.shape[-2]
     n_pages = table.shape[1]
     shape = (b, h, n_pages, page, hd)
     level, kw = resolve_plan("decode_attention", shape, k_pages.dtype,
@@ -194,7 +199,8 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if pages_per_tile is None:
         pages_per_tile = heuristic_pages_per_tile(n_pages, page)
     return _decode_attention(q, k_pages, v_pages, table, lengths,
-                             k_scale, v_scale, window=window, level=level,
+                             k_scale, v_scale, layer, window=window,
+                             level=level,
                              pages_per_tile=int(pages_per_tile),
                              interpret=interpret)
 
@@ -202,13 +208,15 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 @functools.partial(jax.jit, static_argnames=("window", "level",
                                              "pages_per_tile", "interpret"))
 def _prefill_attention(q, k_pages, v_pages, table, starts, k_scale,
-                       v_scale, *, window: int, level: Level,
+                       v_scale, layer, *, window: int, level: Level,
                        pages_per_tile: int, interpret: bool) -> jax.Array:
     if level in (Level.T0_NAIVE, Level.T1_PIPELINED):
         return ref.prefill_attention_ref(q, k_pages, v_pages, table, starts,
-                                         k_scale, v_scale, window=window)
+                                         k_scale, v_scale, window=window,
+                                         layer=layer)
     return prefill_attention_pallas(q, k_pages, v_pages, table, starts,
-                                    k_scale, v_scale, window=window,
+                                    k_scale, v_scale, layer=layer,
+                                    window=window,
                                     pages_per_tile=pages_per_tile,
                                     interpret=interpret)
 
@@ -217,6 +225,7 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       table: jax.Array, starts: jax.Array,
                       k_scale: Optional[jax.Array] = None,
                       v_scale: Optional[jax.Array] = None, *,
+                      layer: Optional[jax.Array] = None,
                       window: int = 0,
                       level: Level = Level.T3_REPLICATED,
                       pages_per_tile: Optional[int] = None,
@@ -226,7 +235,8 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
     q (B, C, H, hd) — one chunk of C prompt tokens per slot, already
     written into the pools; k_pages / v_pages (P, Hkv, page, hd) shared
-    page pools; table (B, n_pages) int32 page ids; starts (B,) int32
+    page pools, or layer-stacked (L, P, Hkv, page, hd) pools read at the
+    scalar ``layer``; table (B, n_pages) int32 page ids; starts (B,) int32
     page-aligned chunk offsets (slot b's queries sit at positions
     ``starts[b] + [0, C)``).  int8 pools additionally take ``k_scale`` /
     ``v_scale`` (P, Hkv) f32 per-page per-kv-head scales (in-kernel
@@ -241,7 +251,7 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if interpret is None:
         interpret = interpret_default()
     b, c, h, hd = q.shape
-    page = k_pages.shape[2]
+    page = k_pages.shape[-2]
     n_pages = table.shape[1]
     shape = (b, c, h, n_pages, page, hd)
     level, kw = resolve_plan("prefill_attention", shape, k_pages.dtype,
@@ -251,7 +261,8 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if pages_per_tile is None:
         pages_per_tile = heuristic_pages_per_tile(n_pages, page)
     return _prefill_attention(q, k_pages, v_pages, table, starts,
-                              k_scale, v_scale, window=window, level=level,
+                              k_scale, v_scale, layer, window=window,
+                              level=level,
                               pages_per_tile=int(pages_per_tile),
                               interpret=interpret)
 
@@ -370,17 +381,18 @@ def attention_blockwise_reference(q, k, v, *, causal, window, softcap,
 
 
 def decode_attention_reference(q, k_pages, v_pages, table, lengths,
-                               k_scale=None, v_scale=None, *,
+                               k_scale=None, v_scale=None, layer=None, *,
                                window, softcap, accum_dtype, out_dtype):
     """Paged ragged decode reference: gather pages to a dense view
-    (dequantizing int8 pools through the per-page scales), mask by
-    per-slot length (and window), softmax in ``accum_dtype``.  The einsum
-    lowering the paged serve path uses when the kernel route is off."""
+    (dequantizing int8 pools through the per-page scales; a layer-stacked
+    pool at ``layer``), mask by per-slot length (and window), softmax in
+    ``accum_dtype``.  The einsum lowering the paged serve path uses when
+    the kernel route is off."""
     b, h, hd = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[-3]
     grp = h // hkv
-    k = ref._gather_pages(k_pages, table, k_scale)
-    v = ref._gather_pages(v_pages, table, v_scale)
+    k = ref._gather_pages(k_pages, table, k_scale, layer)
+    v = ref._gather_pages(v_pages, table, v_scale, layer)
     if grp > 1:
         k = jnp.broadcast_to(k[:, :, :, None, :],
                              k.shape[:3] + (grp, hd)).reshape(b, -1, h, hd)
@@ -403,20 +415,21 @@ def decode_attention_reference(q, k_pages, v_pages, table, lengths,
 
 
 def prefill_attention_reference(q, k_pages, v_pages, table, starts,
-                                k_scale=None, v_scale=None, *,
+                                k_scale=None, v_scale=None, layer=None, *,
                                 window, softcap, accum_dtype, out_dtype):
     """Paged ragged prefill reference: gather pages to a dense view
-    (dequantizing int8 pools through the per-page scales), mask causally
+    (dequantizing int8 pools through the per-page scales; a layer-stacked
+    pool at ``layer``), mask causally
     against each chunk's positions (and the sliding window), softmax in
     ``accum_dtype`` — numerically identical to the gather +
     naive-attention path chunked prefill took before this op existed."""
     b, c, h, hd = q.shape
-    hkv, page = k_pages.shape[1], k_pages.shape[2]
+    hkv, page = k_pages.shape[-3], k_pages.shape[-2]
     grp = h // hkv
     registry.assert_no_dense_scores("prefill_attention_reference",
                                     c, table.shape[1] * page)
-    k = ref._gather_pages(k_pages, table, k_scale)
-    v = ref._gather_pages(v_pages, table, v_scale)
+    k = ref._gather_pages(k_pages, table, k_scale, layer)
+    v = ref._gather_pages(v_pages, table, v_scale, layer)
     if grp > 1:
         k = jnp.broadcast_to(k[:, :, :, None, :],
                              k.shape[:3] + (grp, hd)).reshape(b, -1, h, hd)
@@ -555,24 +568,24 @@ def _paged_pools_ok(q, k_pages, v_pages, k_scale, v_scale) -> bool:
                    for t in (k_pages, v_pages))
     if v_scale is None:
         return False
-    expect = (k_pages.shape[0], k_pages.shape[1])
+    expect = k_pages.shape[-4:-2]          # one layer's (P, Hkv)
     return (all(t.dtype == jnp.int8 for t in (k_pages, v_pages))
             and all(jnp.issubdtype(s.dtype, jnp.floating)
                     and s.shape == expect for s in (k_scale, v_scale)))
 
 
 def _decode_eligible(st, q, k_pages, v_pages, table, lengths,
-                     k_scale=None, v_scale=None) -> bool:
+                     k_scale=None, v_scale=None, layer=None) -> bool:
     if st["softcap"] > 0:
         return False
-    if q.shape[1] % k_pages.shape[1]:
+    if q.shape[1] % k_pages.shape[-3]:
         return False              # GQA group must divide evenly
     return _paged_pools_ok(q, k_pages, v_pages, k_scale, v_scale)
 
 
 def _decode_plan_shape(st, q, k_pages, v_pages, table, lengths,
-                       k_scale=None, v_scale=None):
-    return (q.shape[0], q.shape[1], table.shape[1], k_pages.shape[2],
+                       k_scale=None, v_scale=None, layer=None):
+    return (q.shape[0], q.shape[1], table.shape[1], k_pages.shape[-2],
             q.shape[2])
 
 
@@ -583,19 +596,19 @@ def _paged_plan_dtype(st, q, k_pages, *rest):
 
 
 def _decode_ref_lowering(ctx, q, k_pages, v_pages, table, lengths,
-                         k_scale=None, v_scale=None):
+                         k_scale=None, v_scale=None, layer=None):
     kw = ctx.kw
     return decode_attention_reference(
-        q, k_pages, v_pages, table, lengths, k_scale, v_scale,
+        q, k_pages, v_pages, table, lengths, k_scale, v_scale, layer,
         window=kw["window"], softcap=kw["softcap"],
         accum_dtype=kw["accum_dtype"], out_dtype=kw["out_dtype"])
 
 
 def _decode_kernel_lowering(ctx, q, k_pages, v_pages, table, lengths,
-                            k_scale=None, v_scale=None):
+                            k_scale=None, v_scale=None, layer=None):
     kw = ctx.kw
     out = decode_attention(q, k_pages, v_pages, table, lengths,
-                           k_scale, v_scale,
+                           k_scale, v_scale, layer=layer,
                            window=kw["window"], plan=ctx.ops_plan())
     return out.astype(kw["out_dtype"])
 
@@ -628,34 +641,34 @@ def _decode_bad_example():
 
 
 def _prefill_eligible(st, q, k_pages, v_pages, table, starts,
-                      k_scale=None, v_scale=None) -> bool:
+                      k_scale=None, v_scale=None, layer=None) -> bool:
     if st["softcap"] > 0:
         return False
-    if q.shape[2] % k_pages.shape[1]:
+    if q.shape[2] % k_pages.shape[-3]:
         return False              # GQA group must divide evenly
     return _paged_pools_ok(q, k_pages, v_pages, k_scale, v_scale)
 
 
 def _prefill_plan_shape(st, q, k_pages, v_pages, table, starts,
-                        k_scale=None, v_scale=None):
+                        k_scale=None, v_scale=None, layer=None):
     return (q.shape[0], q.shape[1], q.shape[2], table.shape[1],
-            k_pages.shape[2], q.shape[3])
+            k_pages.shape[-2], q.shape[3])
 
 
 def _prefill_ref_lowering(ctx, q, k_pages, v_pages, table, starts,
-                          k_scale=None, v_scale=None):
+                          k_scale=None, v_scale=None, layer=None):
     kw = ctx.kw
     return prefill_attention_reference(
-        q, k_pages, v_pages, table, starts, k_scale, v_scale,
+        q, k_pages, v_pages, table, starts, k_scale, v_scale, layer,
         window=kw["window"], softcap=kw["softcap"],
         accum_dtype=kw["accum_dtype"], out_dtype=kw["out_dtype"])
 
 
 def _prefill_kernel_lowering(ctx, q, k_pages, v_pages, table, starts,
-                             k_scale=None, v_scale=None):
+                             k_scale=None, v_scale=None, layer=None):
     kw = ctx.kw
     out = prefill_attention(q, k_pages, v_pages, table, starts,
-                            k_scale, v_scale,
+                            k_scale, v_scale, layer=layer,
                             window=kw["window"], plan=ctx.ops_plan())
     return out.astype(kw["out_dtype"])
 
@@ -838,12 +851,13 @@ registry.register(registry.OpSpec(
     bad_example=_decode_bad_example,
     tp={
         # heads are the sharded axis: q (B, H, hd) on dim 1, K/V pools
-        # (P, Hkv, page, hd) on dim 1, per-page scales (P, Hkv) on dim 1;
-        # table/lengths are host metadata, replicated. Each shard attends
-        # its own heads against its own pool slice, then the per-shard
-        # (B, H/tp, hd) outputs all-gather back to full heads on dim 1.
+        # ([L,] P, Hkv, page, hd) on their Hkv dim (-3), per-page scales
+        # (P, Hkv) on dim 1; table/lengths and the layer index are host
+        # metadata, replicated. Each shard attends its own heads against
+        # its own pool slice, then the per-shard (B, H/tp, hd) outputs
+        # all-gather back to full heads on dim 1.
         "heads": registry.TPContract(
-            in_axes=(1, 1, 1, None, None, 1, 1),
+            in_axes=(1, -3, -3, None, None, 1, 1, None),
             collective="all_gather",
             gather_axis=1,
         ),
@@ -862,10 +876,10 @@ registry.register(registry.OpSpec(
     bad_example=_prefill_bad_example,
     tp={
         # same layout as decode with a chunk axis: q (B, C, H, hd) sharded
-        # on dim 2, pools on dim 1, scales on dim 1; gather restores full
+        # on dim 2, pools on dim -3, scales on dim 1; gather restores full
         # heads on dim 2 of the (B, C, H/tp, hd) per-shard output.
         "heads": registry.TPContract(
-            in_axes=(2, 1, 1, None, None, 1, 1),
+            in_axes=(2, -3, -3, None, None, 1, 1, None),
             collective="all_gather",
             gather_axis=2,
         ),

@@ -25,7 +25,9 @@ still take the reference attention route" item with the same paper stack:
 Layout: q (B, C, H, hd) — B chunked slots, GQA-grouped to (B, Hkv, C*grp,
 hd) so each grid step feeds one (C*grp, page*ppt) MXU score tile;
 k_pages / v_pages (P, Hkv, page, hd) (head-major: one kv head's page is
-a contiguous (page, hd) tile); table (B, n_pages) int32 page ids;
+a contiguous (page, hd) tile), or the layer-stacked (L, P, Hkv, page, hd)
+pool read at a scalar-prefetched ``layer`` as in the decode kernel;
+table (B, n_pages) int32 page ids;
 starts (B,) int32 page-aligned chunk offsets — slot b's queries sit at
 positions ``starts[b] + [0, C)`` and its live KV length is
 ``starts[b] + C`` (the chunk was just written into its page).  Padded
@@ -45,9 +47,10 @@ from jax.experimental.pallas import tpu as pltpu
 from ..common import tpu_compiler_params
 
 
-def _prefill_kernel(starts_ref, table_ref, *rest, n_tiles: int,
+def _prefill_kernel(starts_ref, table_ref, layer_ref, *rest, n_tiles: int,
                     page: int, ppt: int, grp: int, chunk: int, window: int,
                     scale: float, quantized: bool):
+    del layer_ref                     # read by the page index maps only
     if quantized:
         k_scale_ref, v_scale_ref, q_ref, *refs = rest
     else:
@@ -123,18 +126,23 @@ def prefill_attention_pallas(q: jax.Array, k_pages: jax.Array,
                              v_pages: jax.Array, table: jax.Array,
                              starts: jax.Array,
                              k_scale: jax.Array = None,
-                             v_scale: jax.Array = None, *, window: int = 0,
-                             pages_per_tile: int = 1,
+                             v_scale: jax.Array = None, *, layer=None,
+                             window: int = 0, pages_per_tile: int = 1,
                              interpret: bool = False) -> jax.Array:
-    """q (B, C, H, hd); k/v_pages (P, Hkv, page, hd); table (B, n_pages);
+    """q (B, C, H, hd); k/v_pages (P, Hkv, page, hd), or (L, P, Hkv,
+    page, hd) with the scalar ``layer`` to read; table (B, n_pages);
     starts (B,) page-aligned chunk offsets.  Returns (B, C, H, hd) f32.
 
     int8 pools additionally take ``k_scale`` / ``v_scale`` (P, Hkv) f32
-    per-page per-kv-head scales; they ride the scalar-prefetch path next
-    to ``table`` and the page tiles dequantize at load time."""
+    per-page per-kv-head scales (one layer's, also for a stacked pool);
+    they ride the scalar-prefetch path next to ``table`` and the page
+    tiles dequantize at load time."""
     quantized = k_scale is not None
+    if k_pages.ndim == 4:
+        # an unstacked pool is a stack of one layer (a free reshape)
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     b, c, h, hd = q.shape
-    _, hkv, page, _ = k_pages.shape
+    _, _, hkv, page, _ = k_pages.shape
     n_pages = table.shape[1]
     assert h % hkv == 0, (h, hkv)
     grp = h // hkv
@@ -158,20 +166,21 @@ def prefill_attention_pallas(q: jax.Array, k_pages: jax.Array,
         quantized=quantized)
 
     # int8 pools prefetch two extra scalar operands (the scale tables), so
-    # every index map takes a *prefetch tail of 2 or 4 refs
+    # the page index maps take a *prefetch tail of 0 or 2 refs
     def page_spec(i):
         # the i-th page stream of a KV tile: tile j holds logical pages
         # [j*ppt, (j+1)*ppt); the scalar-prefetched table resolves the
-        # logical -> physical page id inside the index map (§4.1)
+        # logical -> physical page id inside the index map (§4.1), and the
+        # prefetched layer index the layer of the stack
         return pl.BlockSpec(
-            (1, 1, page, hd),
-            lambda bb, hh, jj, st, tab, *_sc, i=i: (tab[bb, jj * ppt + i],
-                                                    hh, 0, 0))
+            (None, 1, 1, page, hd),
+            lambda bb, hh, jj, st, tab, lyr, *_sc, i=i: (
+                lyr[0], tab[bb, jj * ppt + i], hh, 0, 0))
 
     q_spec = pl.BlockSpec((1, 1, rows, hd),
-                          lambda bb, hh, jj, st, tab, *_sc: (bb, hh, 0, 0))
+                          lambda bb, hh, jj, *_: (bb, hh, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if quantized else 2,
+        num_scalar_prefetch=5 if quantized else 3,
         grid=(b, hkv, n_tiles),
         in_specs=[
             q_spec,
@@ -179,15 +188,15 @@ def prefill_attention_pallas(q: jax.Array, k_pages: jax.Array,
             *[page_spec(i) for i in range(ppt)],
         ],
         out_specs=pl.BlockSpec((1, 1, rows, hd),
-                               lambda bb, hh, jj, st, tab, *_sc:
-                               (bb, hh, 0, 0)),
+                               lambda bb, hh, jj, *_: (bb, hh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),     # running max
             pltpu.VMEM((rows, 1), jnp.float32),     # running denom
             pltpu.VMEM((rows, hd), jnp.float32),    # weighted-V acc
         ],
     )
-    prefetch = (starts.astype(jnp.int32), table)
+    prefetch = (starts.astype(jnp.int32), table,
+                jnp.reshape(layer, (1,)).astype(jnp.int32))
     if quantized:
         prefetch += (k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32))

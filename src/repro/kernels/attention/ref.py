@@ -8,14 +8,16 @@ import jax.numpy as jnp
 
 
 def _gather_pages(pages: jax.Array, table: jax.Array,
-                  scale: jax.Array) -> jax.Array:
+                  scale: jax.Array, layer=None) -> jax.Array:
     """Gather a (P, Hkv, page, hd) pool's pages per slot into a dense
-    (B, n_pages * page, Hkv, hd) view; int8 pools (scale (P, Hkv) f32
+    (B, n_pages * page, Hkv, hd) view — of a layer-stacked (L, P, Hkv,
+    page, hd) pool, those of ``layer``; int8 pools (scale (P, Hkv) f32
     per-page per-kv-head) dequantize to f32 at gather time — the oracle
     twin of the kernels' in-tile dequant."""
     b = table.shape[0]
-    hkv, hd = pages.shape[1], pages.shape[3]
-    g = pages[table]                       # (B, n_pages, Hkv, page, hd)
+    hkv, hd = pages.shape[-3], pages.shape[-1]
+    g = pages[table] if layer is None else pages[layer, table]
+    # g: (B, n_pages, Hkv, page, hd)
     if scale is not None:
         g = g.astype(jnp.float32) * scale[table][:, :, :, None, None]
     return g.transpose(0, 1, 3, 2, 4).reshape(b, -1, hkv, hd)
@@ -26,16 +28,17 @@ def decode_attention_ref(q: jax.Array, k_pages: jax.Array,
                          lengths: jax.Array,
                          k_scale: jax.Array = None,
                          v_scale: jax.Array = None, *,
-                         window: int = 0) -> jax.Array:
+                         window: int = 0, layer=None) -> jax.Array:
     """Oracle for paged ragged decode: gather pages to a dense (B, S, Hkv,
     hd) view (dequantizing int8 pools through ``k_scale`` / ``v_scale``),
     mask key positions past each slot's length (and older than its
-    window), f32 softmax.  q (B, H, hd) -> (B, H, hd) f32."""
+    window), f32 softmax.  A layer-stacked pool is read at ``layer``.
+    q (B, H, hd) -> (B, H, hd) f32."""
     b, h, hd = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[-3]
     grp = h // hkv
-    k = _gather_pages(k_pages, table, k_scale)       # (B, n_pages*page, ...)
-    v = _gather_pages(v_pages, table, v_scale)
+    k = _gather_pages(k_pages, table, k_scale, layer)  # (B, n_pages*page, ..)
+    v = _gather_pages(v_pages, table, v_scale, layer)
     if grp > 1:                                      # GQA group broadcast
         k = jnp.broadcast_to(k[:, :, :, None, :],
                              k.shape[:3] + (grp, hd)).reshape(b, -1, h, hd)
@@ -60,18 +63,18 @@ def prefill_attention_ref(q: jax.Array, k_pages: jax.Array,
                           starts: jax.Array,
                           k_scale: jax.Array = None,
                           v_scale: jax.Array = None, *,
-                          window: int = 0) -> jax.Array:
+                          window: int = 0, layer=None) -> jax.Array:
     """Oracle for paged ragged multi-token prefill: gather pages to a
     dense (B, S, Hkv, hd) view (dequantizing int8 pools through
     ``k_scale`` / ``v_scale``), mask causally against each chunk's own
     positions (``starts[b] + [0, C)``; the chunk's own keys are already in
-    the pool) and by the sliding window, f32 softmax.
-    q (B, C, H, hd) -> (B, C, H, hd) f32."""
+    the pool) and by the sliding window, f32 softmax.  A layer-stacked
+    pool is read at ``layer``.  q (B, C, H, hd) -> (B, C, H, hd) f32."""
     b, c, h, hd = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[-3]
     grp = h // hkv
-    k = _gather_pages(k_pages, table, k_scale)       # (B, n_pages*page, ...)
-    v = _gather_pages(v_pages, table, v_scale)
+    k = _gather_pages(k_pages, table, k_scale, layer)  # (B, n_pages*page, ..)
+    v = _gather_pages(v_pages, table, v_scale, layer)
     if grp > 1:                                      # GQA group broadcast
         k = jnp.broadcast_to(k[:, :, :, None, :],
                              k.shape[:3] + (grp, hd)).reshape(b, -1, h, hd)

@@ -214,6 +214,7 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      table: jax.Array, lengths: jax.Array,
                      k_scale: Optional[jax.Array] = None,
                      v_scale: Optional[jax.Array] = None, *,
+                     layer: Optional[jax.Array] = None,
                      window: int = 0, softcap: float = 0.0,
                      accum_dtype: Any = jnp.float32,
                      out_dtype: Any = None,
@@ -221,19 +222,19 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Ragged decode attention over a paged KV cache — the serving hot path.
 
     q (B, H, hd) one query token per slot; k_pages / v_pages (P, Hkv,
-    page, hd) shared pools; table (B, n_pages) logical->physical page ids;
-    lengths (B,) valid tokens per slot (0 = inactive -> zero output).
-    int8 pools additionally pass ``k_scale`` / ``v_scale`` (P, Hkv) f32
-    per-page per-kv-head scales (both or neither); the kernel dequantizes
-    page tiles at load time, the reference at gather time.
+    page, hd) shared pools, or the model's layer-stacked (L, P, Hkv, page,
+    hd) pools with the scalar ``layer`` to read (the pool's rank says
+    which); table (B, n_pages) logical->physical page ids; lengths (B,)
+    valid tokens per slot (0 = inactive -> zero output).  int8 pools
+    additionally pass ``k_scale`` / ``v_scale`` (P, Hkv) f32 per-page
+    per-kv-head scales of the layer read (both or neither); the kernel
+    dequantizes page tiles at load time, the reference at gather time.
     Returns (B, H, hd) in ``out_dtype`` (default q's dtype).  Inference
     only — no custom VJP; the kernel route consults the tuned-plan cache
     for KV-tile geometry (keyed on the POOL dtype).
     """
     out_dtype = q.dtype if out_dtype is None else out_dtype
-    args = (q, k_pages, v_pages, table, lengths)
-    if k_scale is not None:
-        args += (k_scale, v_scale)
+    args = (q, k_pages, v_pages, table, lengths, k_scale, v_scale, layer)
     # "heads" is the op's single sharding contract: q heads and KV pools
     # device-local, output all-gathered back to full head width so the
     # (replicated) out-projection sees every head.  Inert unsharded.
@@ -248,6 +249,7 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       table: jax.Array, starts: jax.Array,
                       k_scale: Optional[jax.Array] = None,
                       v_scale: Optional[jax.Array] = None, *,
+                      layer: Optional[jax.Array] = None,
                       window: int = 0, softcap: float = 0.0,
                       accum_dtype: Any = jnp.float32,
                       out_dtype: Any = None,
@@ -260,15 +262,14 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     [0, C)`` and attend causally over the cached history plus the chunk
     itself (padded tail positions are hidden by causality).  Returns
     (B, C, H, hd) in ``out_dtype`` (default q's dtype).  int8 pools pass
-    ``k_scale`` / ``v_scale`` (P, Hkv) f32 scales like ``decode_attention``.
+    ``k_scale`` / ``v_scale`` (P, Hkv) f32 scales, and a layer-stacked pool
+    its ``layer``, like ``decode_attention``.
     Inference only — no custom VJP; the first op registered end-to-end
     through the registry (kernel, oracle, tune space, plan key: one
     ``OpSpec``).
     """
     out_dtype = q.dtype if out_dtype is None else out_dtype
-    args = (q, k_pages, v_pages, table, starts)
-    if k_scale is not None:
-        args += (k_scale, v_scale)
+    args = (q, k_pages, v_pages, table, starts, k_scale, v_scale, layer)
     return _call(
         "prefill_attention", *args,
         statics=dict(window=int(window), softcap=float(softcap),

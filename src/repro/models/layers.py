@@ -297,13 +297,41 @@ def attention_decode(p: Params, s: AttnSpec, x: jax.Array, pos: jax.Array,
     return out, k_cache, v_cache
 
 
+def _at(layer, *idx):
+    """Index of a paged-pool leaf: a layer-stacked ([L,] P, ...) leaf is
+    addressed at ``layer`` first; an unstacked one (``layer`` None) as is."""
+    return idx if layer is None else (layer,) + idx
+
+
+def _rows_at(layer, pid, off, n_kv_heads: int):
+    """Index of one token row per (slot, kv head) — page ``pid[b]``, row
+    ``off[b]`` — with every pool dim but hd indexed by a scalar: the
+    scatter then has hd alone as its window, so the compiler keeps the
+    pool in the row-major layout the attention kernels read.  (Slicing the
+    head axis instead, ``[pid, :, off]``, makes the TPU compiler move the
+    head axis minor inside the step and copy the whole pool to and from
+    that layout around every kernel call.)"""
+    heads = jnp.arange(n_kv_heads)[None, :]
+    return _at(layer, pid[:, None], heads, off[:, None])
+
+
+def _layer_scales(k_scale, v_scale, layer):
+    """One layer's (P, Hkv) int8 scales for the attention op: only the
+    scales are sliced per layer (65 KB at a 4097-page pool), never a
+    pool; the kernels prefetch them into SMEM, which cannot hold a stack."""
+    if layer is None or k_scale is None:
+        return k_scale, v_scale
+    return k_scale[layer], v_scale[layer]
+
+
 def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
                            lengths: jax.Array, table: jax.Array,
                            k_pages: jax.Array, v_pages: jax.Array,
                            dt: DtypePolicy,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
-                           positions_override: Optional[jax.Array] = None
+                           positions_override: Optional[jax.Array] = None,
+                           layer: Optional[jax.Array] = None
                            ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                       Optional[jax.Array],
                                       Optional[jax.Array]]:
@@ -316,10 +344,14 @@ def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
     (P, Hkv, page, hd) pools.  int8 pools additionally carry ``k_scale`` /
     ``v_scale`` (P, Hkv) f32: the append runs the running-max requantize
     (``core.quant``) and the scales ride into the kernel's scalar-prefetch
-    path.  Returns (out (B,1,d), k_pages, v_pages, k_scale, v_scale).
+    path.  With ``layer`` the pools (and scales) are the model's
+    layer-stacked (L, P, Hkv, page, hd) leaves: the token rows are written
+    in place at ``[layer, page, head, offset]`` and the op reads that layer
+    out of the stack, so no layer's pool is ever copied.
+    Returns (out (B,1,d), k_pages, v_pages, k_scale, v_scale).
     """
     b = x.shape[0]
-    page = k_pages.shape[2]
+    page = k_pages.shape[-2]
     positions = (positions_override if positions_override is not None
                  else lengths[:, None].astype(jnp.int32))
     q, k, v = _qkv(p, s, x, positions, dt)
@@ -331,22 +363,25 @@ def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
         # quantize-on-write: gather the B target pages, append with the
         # running-max rescale, scatter pages + scales back (slots are
         # distinct; inactive slots all hit the never-read trash page)
+        at = _at(layer, pid)
         pk, sk = quant.append_token_quantized(
-            k_pages[pid], k_scale[pid], k[:, 0], off)
+            k_pages[at], k_scale[at], k[:, 0], off)
         pv, sv = quant.append_token_quantized(
-            v_pages[pid], v_scale[pid], v[:, 0], off)
-        k_pages = k_pages.at[pid].set(pk)
-        v_pages = v_pages.at[pid].set(pv)
-        k_scale = k_scale.at[pid].set(sk)
-        v_scale = v_scale.at[pid].set(sv)
+            v_pages[at], v_scale[at], v[:, 0], off)
+        k_pages = k_pages.at[at].set(pk)
+        v_pages = v_pages.at[at].set(pv)
+        k_scale = k_scale.at[at].set(sk)
+        v_scale = v_scale.at[at].set(sv)
     else:
-        # (pid, :, off) picks each slot's token row across all kv heads
-        k_pages = k_pages.at[pid, :, off].set(k[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[pid, :, off].set(v[:, 0].astype(v_pages.dtype))
+        # each slot's token row, across all kv heads
+        at = _rows_at(layer, pid, off, k.shape[2])
+        k_pages = k_pages.at[at].set(k[:, 0].astype(k_pages.dtype))
+        v_pages = v_pages.at[at].set(v[:, 0].astype(v_pages.dtype))
     # GQA grouping happens inside the decode kernel/reference, so the
     # pools stay at Hkv heads end-to-end (no expanded copy in HBM)
     out = dispatch.decode_attention(
-        q[:, 0], k_pages, v_pages, table, lengths + 1, k_scale, v_scale,
+        q[:, 0], k_pages, v_pages, table, lengths + 1,
+        *_layer_scales(k_scale, v_scale, layer), layer=layer,
         window=s.window, softcap=s.softcap, accum_dtype=dt.accum,
         out_dtype=dt.compute, policy=s.dispatch)
     return (_out_proj(p, s, out[:, None], dt), k_pages, v_pages,
@@ -359,7 +394,8 @@ def attention_prefill_paged(p: Params, s: AttnSpec, x: jax.Array,
                             dt: DtypePolicy,
                             k_scale: Optional[jax.Array] = None,
                             v_scale: Optional[jax.Array] = None,
-                            positions_override: Optional[jax.Array] = None
+                            positions_override: Optional[jax.Array] = None,
+                            layer: Optional[jax.Array] = None
                             ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                        Optional[jax.Array],
                                        Optional[jax.Array]]:
@@ -374,33 +410,37 @@ def attention_prefill_paged(p: Params, s: AttnSpec, x: jax.Array,
     itself.  Slots must be distinct (each chunk writes its own physical
     page).  int8 pools carry ``k_scale`` / ``v_scale`` (P, Hkv) f32: a
     whole-page write gets a clean abs-max scale (``quant.quantize_pages``).
+    With ``layer`` the pools are layer-stacked, as in
+    ``attention_decode_paged``: each chunk's page is written in place at
+    ``[layer, page]``.
     Returns (out (B,C,d), k_pages, v_pages, k_scale, v_scale).
     """
     b, c, _ = x.shape
-    page = k_pages.shape[2]
+    page = k_pages.shape[-2]
     positions = (positions_override if positions_override is not None
                  else (starts[:, None] + jnp.arange(c)[None, :]
                        ).astype(jnp.int32))
     q, k, v = _qkv(p, s, x, positions, dt)
-    pid = tables[jnp.arange(b), starts // page]
+    at = _at(layer, tables[jnp.arange(b), starts // page])
     # (B, C=page, Hkv, hd) -> the pools' head-major (B, Hkv, page, hd)
     kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     if k_scale is not None:
         pk, sk = quant.quantize_pages(kt)
         pv, sv = quant.quantize_pages(vt)
-        k_pages = k_pages.at[pid].set(pk)
-        v_pages = v_pages.at[pid].set(pv)
-        k_scale = k_scale.at[pid].set(sk)
-        v_scale = v_scale.at[pid].set(sv)
+        k_pages = k_pages.at[at].set(pk)
+        v_pages = v_pages.at[at].set(pv)
+        k_scale = k_scale.at[at].set(sk)
+        v_scale = v_scale.at[at].set(sv)
     else:
-        k_pages = k_pages.at[pid].set(kt.astype(k_pages.dtype))
-        v_pages = v_pages.at[pid].set(vt.astype(v_pages.dtype))
+        k_pages = k_pages.at[at].set(kt.astype(k_pages.dtype))
+        v_pages = v_pages.at[at].set(vt.astype(v_pages.dtype))
     # multi-token ragged prefill through dispatch: each chunk's queries
     # attend causally over the cached history plus the chunk itself (just
     # written into its page); GQA grouping happens inside the kernel /
     # reference, so the pools stay at Hkv heads end-to-end
     out = dispatch.prefill_attention(
-        q, k_pages, v_pages, tables, starts, k_scale, v_scale,
+        q, k_pages, v_pages, tables, starts,
+        *_layer_scales(k_scale, v_scale, layer), layer=layer,
         window=s.window, softcap=s.softcap, accum_dtype=dt.accum,
         out_dtype=dt.compute, policy=s.dispatch)
     return _out_proj(p, s, out, dt), k_pages, v_pages, k_scale, v_scale
@@ -412,7 +452,8 @@ def attention_verify_paged(p: Params, s: AttnSpec, x: jax.Array,
                            dt: DtypePolicy,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
-                           positions_override: Optional[jax.Array] = None
+                           positions_override: Optional[jax.Array] = None,
+                           layer: Optional[jax.Array] = None
                            ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                       Optional[jax.Array],
                                       Optional[jax.Array]]:
@@ -431,11 +472,12 @@ def attention_verify_paged(p: Params, s: AttnSpec, x: jax.Array,
     legal on kernel and reference routes alike.  Rejected drafts are
     rolled back by the HOST truncating ``lengths``; their stale K/V
     payload (and any int8 running-max scale growth) stays in the pool,
-    masked off by every later ``kpos < length`` read.
+    masked off by every later ``kpos < length`` read.  With ``layer`` the
+    pools are layer-stacked, as in ``attention_decode_paged``.
     Returns (out (B,W,d), k_pages, v_pages, k_scale, v_scale).
     """
     b, w, _ = x.shape
-    page = k_pages.shape[2]
+    page = k_pages.shape[-2]
     positions = (positions_override if positions_override is not None
                  else (lengths[:, None] + jnp.arange(w)[None, :]
                        ).astype(jnp.int32))
@@ -453,21 +495,22 @@ def attention_verify_paged(p: Params, s: AttnSpec, x: jax.Array,
                         0)
         off = pos % page
         if k_scale is not None:
+            at = _at(layer, pid)
             pk, sk = quant.append_token_quantized(
-                k_pages[pid], k_scale[pid], k[:, t], off)
+                k_pages[at], k_scale[at], k[:, t], off)
             pv, sv = quant.append_token_quantized(
-                v_pages[pid], v_scale[pid], v[:, t], off)
-            k_pages = k_pages.at[pid].set(pk)
-            v_pages = v_pages.at[pid].set(pv)
-            k_scale = k_scale.at[pid].set(sk)
-            v_scale = v_scale.at[pid].set(sv)
+                v_pages[at], v_scale[at], v[:, t], off)
+            k_pages = k_pages.at[at].set(pk)
+            v_pages = v_pages.at[at].set(pv)
+            k_scale = k_scale.at[at].set(sk)
+            v_scale = v_scale.at[at].set(sv)
         else:
-            k_pages = k_pages.at[pid, :, off].set(
-                k[:, t].astype(k_pages.dtype))
-            v_pages = v_pages.at[pid, :, off].set(
-                v[:, t].astype(v_pages.dtype))
+            at = _rows_at(layer, pid, off, k.shape[2])
+            k_pages = k_pages.at[at].set(k[:, t].astype(k_pages.dtype))
+            v_pages = v_pages.at[at].set(v[:, t].astype(v_pages.dtype))
     out = dispatch.prefill_attention(
-        q, k_pages, v_pages, table, lengths, k_scale, v_scale,
+        q, k_pages, v_pages, table, lengths,
+        *_layer_scales(k_scale, v_scale, layer), layer=layer,
         window=s.window, softcap=s.softcap, accum_dtype=dt.accum,
         out_dtype=dt.compute, policy=s.dispatch)
     return _out_proj(p, s, out, dt), k_pages, v_pages, k_scale, v_scale

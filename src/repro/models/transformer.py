@@ -219,12 +219,15 @@ def layer_decode(p: Params, cfg: ArchConfig, kind: LayerKind, x: jax.Array,
                  cache: Dict[str, Any], pos: jax.Array, dt: DtypePolicy,
                  positions_override=None,
                  opts: Optional[ExecOptions] = None,
-                 paged: Optional[Tuple[jax.Array, jax.Array]] = None
+                 paged: Optional[Tuple[jax.Array, jax.Array]] = None,
+                 layer: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, Dict[str, Any]]:
     """One decode token through one layer.  ``paged`` = (lengths, table)
     switches attention layers to the paged-KV ragged path (``pos`` is then
     ignored — each slot decodes at its own length); recurrent mixers and
-    FFNs are cache-layout-agnostic and run unchanged either way."""
+    FFNs are cache-layout-agnostic and run unchanged either way.
+    ``layer`` marks the page pools as the layer-stacked leaves of the
+    scanned stack (see ``_layer_step``)."""
     mixer, ffn = kind
     new_cache = dict(cache)
     h = layers.rmsnorm(p["ln1"], x)
@@ -236,7 +239,7 @@ def layer_decode(p: Params, cfg: ArchConfig, kind: LayerKind, x: jax.Array,
                 p["attn"], spec, h, lengths, table,
                 cache["k_pages"], cache["v_pages"], dt,
                 cache.get("k_scale"), cache.get("v_scale"),
-                positions_override=positions_override)
+                positions_override=positions_override, layer=layer)
             new_cache["k_pages"], new_cache["v_pages"] = kp, vp
             if ks is not None:
                 new_cache["k_scale"], new_cache["v_scale"] = ks, vs
@@ -319,7 +322,8 @@ def layer_prefill_paged(p: Params, cfg: ArchConfig, kind: LayerKind,
                         x: jax.Array, cache: Dict[str, Any],
                         starts: jax.Array, tables: jax.Array,
                         dt: DtypePolicy, positions_override=None,
-                        opts: Optional[ExecOptions] = None
+                        opts: Optional[ExecOptions] = None,
+                        layer: Optional[jax.Array] = None
                         ) -> Tuple[jax.Array, Dict[str, Any]]:
     """One page-aligned prompt chunk each of B distinct slots through one
     layer (x (B, C, d), starts (B,), tables (B, n_pages)).
@@ -337,7 +341,7 @@ def layer_prefill_paged(p: Params, cfg: ArchConfig, kind: LayerKind,
             p["attn"], spec, h, starts, tables,
             cache["k_pages"], cache["v_pages"], dt,
             cache.get("k_scale"), cache.get("v_scale"),
-            positions_override=positions_override)
+            positions_override=positions_override, layer=layer)
         new_cache["k_pages"], new_cache["v_pages"] = kp, vp
         if ks is not None:
             new_cache["k_scale"], new_cache["v_scale"] = ks, vs
@@ -363,7 +367,8 @@ def layer_verify_paged(p: Params, cfg: ArchConfig, kind: LayerKind,
                        x: jax.Array, cache: Dict[str, Any],
                        lengths: jax.Array, tables: jax.Array,
                        dt: DtypePolicy, positions_override=None,
-                       opts: Optional[ExecOptions] = None
+                       opts: Optional[ExecOptions] = None,
+                       layer: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, Dict[str, Any]]:
     """One speculative verify window of B distinct slots through one layer
     (x (B, W, d), lengths (B,), tables (B, n_pages)).  Same structural
@@ -378,7 +383,7 @@ def layer_verify_paged(p: Params, cfg: ArchConfig, kind: LayerKind,
             p["attn"], spec, h, lengths, tables,
             cache["k_pages"], cache["v_pages"], dt,
             cache.get("k_scale"), cache.get("v_scale"),
-            positions_override=positions_override)
+            positions_override=positions_override, layer=layer)
         new_cache["k_pages"], new_cache["v_pages"] = kp, vp
         if ks is not None:
             new_cache["k_scale"], new_cache["v_scale"] = ks, vs
@@ -398,6 +403,27 @@ def layer_verify_paged(p: Params, cfg: ArchConfig, kind: LayerKind,
         raise ValueError(
             f"speculative verify requires stateless FFNs, got {ffn}")
     return x + h, new_cache
+
+
+# paged-pool leaves stay layer-stacked through the scanned stack and are
+# written in place at the layer index; every other cache leaf (dense KV
+# rectangles, recurrent state) is one layer's slice, written back after
+_POOL_LEAVES = frozenset(("k_pages", "v_pages", "k_scale", "v_scale"))
+
+
+def _layer_step(fn, cache: Dict[str, Any], layer):
+    """Run ``fn(layer_cache) -> (x, new_layer_cache)`` for one layer of a
+    stacked cache dict and return (x, the updated stacked dict).
+
+    The stacked cache is the layer scan's carry, so the in-place writes
+    land in the donated buffer: pools pass whole (``fn`` addresses them
+    at ``layer``), other leaves are sliced and updated at ``layer``."""
+    local = {k: a if k in _POOL_LEAVES else a[layer]
+             for k, a in cache.items()}
+    x, new = fn(local)
+    return x, {k: new[k] if k in _POOL_LEAVES else
+               jax.lax.dynamic_update_index_in_dim(a, new[k], layer, 0)
+               for k, a in cache.items()}
 
 
 def paged_supported(cfg: ArchConfig) -> bool:
@@ -543,6 +569,41 @@ class Model:
             aux_total += aux
         return x, aux_total
 
+    def _cached_stack(self, one, x, stack_params, stack_cache):
+        """The scanned stack of a decode-time step: ``one(p, kind, x,
+        layer_cache, layer=) -> (x, new_layer_cache)`` for every layer of
+        every period.  Returns (x, the new stacked cache list).
+
+        The stacked cache is the **carry** of the layer scan (the weights
+        and the period index are its xs), never xs/ys: a scan that takes
+        the cache as xs copies each layer's pool out of the stack and back
+        into a fresh ys buffer every step.  Carried, the paged pools are
+        written in place at the layer index (``_layer_step``).  Cost mode
+        unrolls the same body in a python loop."""
+        period, n = self.layout.period, self.layout.n_periods
+
+        def body(carry, pp, layer):
+            x, cs = carry
+            cs = list(cs)
+            for j, kind in enumerate(period):
+                x, cs[j] = _layer_step(
+                    lambda c: one(pp[j], kind, x, c, layer=layer),
+                    cs[j], layer)
+            return x, tuple(cs)
+
+        carry = (x, tuple(stack_cache))
+        if self.opts.scan_layers:
+            carry, _ = jax.lax.scan(
+                lambda c, xs: (body(c, *xs), None), carry,
+                (tuple(stack_params), jnp.arange(n)))
+        else:
+            for i in range(n):
+                carry = body(
+                    carry, jax.tree.map(lambda a: a[i], tuple(stack_params)),
+                    i)
+        x, cs = carry
+        return x, list(cs)
+
     def _head(self, params: Params) -> jax.Array:
         head = params["embed"].T if self.cfg.tie_embeddings \
             else params["head"]
@@ -622,39 +683,12 @@ class Model:
             new_cache["prefix"].append(nc)
 
         if lay.n_periods:
-            if opts.scan_layers:
-                def body(x, slices):
-                    pp, cc = slices
-                    ncs = []
-                    for j, kind in enumerate(lay.period):
-                        x, nc = layer_decode(pp[j], cfg, kind, x, cc[j],
-                                             pos, dt, pos_override,
-                                             opts=opts, paged=paged)
-                        ncs.append(nc)
-                    return x, tuple(ncs)
-
-                x, ncs = jax.lax.scan(
-                    body, x, (tuple(params["stack"]), tuple(cache["stack"])))
-                new_cache["stack"] = list(ncs)
-            else:
-                stacked_new = None
-                for i in range(lay.n_periods):
-                    pp = jax.tree.map(lambda a: a[i], tuple(params["stack"]))
-                    cc = jax.tree.map(lambda a: a[i], tuple(cache["stack"]))
-                    ncs = []
-                    for j, kind in enumerate(lay.period):
-                        x, nc = layer_decode(pp[j], cfg, kind, x, cc[j],
-                                             pos, dt, pos_override,
-                                             opts=opts, paged=paged)
-                        ncs.append(nc)
-                    ncs = tuple(ncs)
-                    if stacked_new is None:
-                        stacked_new = jax.tree.map(
-                            lambda a: jnp.zeros((lay.n_periods,) + a.shape,
-                                                a.dtype), ncs)
-                    stacked_new = jax.tree.map(
-                        lambda buf, a: buf.at[i].set(a), stacked_new, ncs)
-                new_cache["stack"] = list(stacked_new)
+            def one(p, kind, x, c, layer):
+                return layer_decode(p, cfg, kind, x, c, pos, dt,
+                                    pos_override, opts=opts, paged=paged,
+                                    layer=layer)
+            x, new_cache["stack"] = self._cached_stack(
+                one, x, params["stack"], cache["stack"])
 
         for p, kind, c in zip(params["tail"], lay.tail, cache["tail"]):
             x, nc = layer_decode(p, cfg, kind, x, c, pos, dt, pos_override,
@@ -668,7 +702,10 @@ class Model:
     def init_paged_cache(self, slots: int, max_len: int, page_size: int,
                          total_pages: Optional[int] = None
                          ) -> Dict[str, Any]:
-        """Paged KV cache: per-attention-layer (P, Hkv, page, hd) pools.
+        """Paged KV cache: per-attention-layer (P, Hkv, page, hd) pools,
+        stacked to (n_periods, P, Hkv, page, hd) for the scanned layers
+        (the steps carry the stack through the layer scan and write it in
+        place; see ``_cached_stack``).
 
         Physical page 0 is the TRASH page — the scheduler points inactive
         slots' tables at it so their (masked, discarded) decode writes
@@ -734,10 +771,10 @@ class Model:
                 (starts[:, None] + jnp.arange(c)[None, :])[:, :, None],
                 (b, c, len(cfg.mrope_sections))).astype(jnp.int32)
 
-        def one(p, kind, x, c_in):
+        def one(p, kind, x, c_in, layer=None):
             return layer_prefill_paged(p, cfg, kind, x, c_in, starts,
                                        tables, dt, pos_override,
-                                       opts=opts)
+                                       opts=opts, layer=layer)
 
         new_cache = {"prefix": [], "stack": [], "tail": []}
         for p, kind, cc in zip(params["prefix"], lay.prefix,
@@ -745,20 +782,8 @@ class Model:
             x, nc = one(p, kind, x, cc)
             new_cache["prefix"].append(nc)
         if lay.n_periods:
-            def body(x, slices):
-                pp, cc = slices
-                ncs = []
-                for j, kind in enumerate(lay.period):
-                    x, nc = one(pp[j], kind, x, cc[j])
-                    ncs.append(nc)
-                return x, tuple(ncs)
-            if opts.scan_layers:
-                x, ncs = jax.lax.scan(
-                    body, x, (tuple(params["stack"]), tuple(cache["stack"])))
-                new_cache["stack"] = list(ncs)
-            else:
-                raise NotImplementedError(
-                    "paged prefill runs in scan mode (ExecOptions run/mem)")
+            x, new_cache["stack"] = self._cached_stack(
+                one, x, params["stack"], cache["stack"])
         for p, kind, cc in zip(params["tail"], lay.tail, cache["tail"]):
             x, nc = one(p, kind, x, cc)
             new_cache["tail"].append(nc)
@@ -789,9 +814,10 @@ class Model:
                 (lengths[:, None] + jnp.arange(w)[None, :])[:, :, None],
                 (b, w, len(cfg.mrope_sections))).astype(jnp.int32)
 
-        def one(p, kind, x, c_in):
+        def one(p, kind, x, c_in, layer=None):
             return layer_verify_paged(p, cfg, kind, x, c_in, lengths,
-                                      tables, dt, pos_override, opts=opts)
+                                      tables, dt, pos_override, opts=opts,
+                                      layer=layer)
 
         new_cache = {"prefix": [], "stack": [], "tail": []}
         for p, kind, cc in zip(params["prefix"], lay.prefix,
@@ -799,21 +825,8 @@ class Model:
             x, nc = one(p, kind, x, cc)
             new_cache["prefix"].append(nc)
         if lay.n_periods:
-            def body(x, slices):
-                pp, cc = slices
-                ncs = []
-                for j, kind in enumerate(lay.period):
-                    x, nc = one(pp[j], kind, x, cc[j])
-                    ncs.append(nc)
-                return x, tuple(ncs)
-            if opts.scan_layers:
-                x, ncs = jax.lax.scan(
-                    body, x, (tuple(params["stack"]), tuple(cache["stack"])))
-                new_cache["stack"] = list(ncs)
-            else:
-                raise NotImplementedError(
-                    "speculative verify runs in scan mode (ExecOptions "
-                    "run/mem)")
+            x, new_cache["stack"] = self._cached_stack(
+                one, x, params["stack"], cache["stack"])
         for p, kind, cc in zip(params["tail"], lay.tail, cache["tail"]):
             x, nc = one(p, kind, x, cc)
             new_cache["tail"].append(nc)

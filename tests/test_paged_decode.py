@@ -192,6 +192,48 @@ def test_decode_attention_int8_all_archs(arch, empty_plan_cache):
     _check_decode_int8(cfg, cfg.window if "swa" in mixers else 0)
 
 
+def _stacked_pools(kp, vp, kv_dtype, n_layers=3):
+    """A layer-stacked (L, P, Hkv, page, hd) pair from one layer's pools:
+    every layer holds distinct, nonzero data (layer l is the pool scaled
+    by l + 1 and shifted by l), so reading the wrong layer shows.  int8
+    stacks come back quantized with their (L, P, Hkv) scales."""
+    k = jnp.stack([kp.astype(jnp.float32) * (l + 1) + l
+                   for l in range(n_layers)])
+    v = jnp.stack([vp.astype(jnp.float32) * (l + 1) - l
+                   for l in range(n_layers)])
+    if kv_dtype == "int8":
+        return _quantized_pools(k, v)
+    return k.astype(kp.dtype), None, v.astype(vp.dtype), None
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_decode_attention_stacked_pool_reads_its_layer(kv_dtype,
+                                                       empty_plan_cache):
+    """The model's layer-stacked pool plus a layer index reads exactly
+    what the same op reads from that layer's pool alone, on the kernel
+    and the reference route — int8 pools with that layer's scales."""
+    q, kp, vp, table = _paged_inputs(4, 2, 16, jnp.bfloat16)
+    kst, kst_s, vst, vst_s = _stacked_pools(kp, vp, kv_dtype)
+    lengths = jnp.asarray([3, 24, 9], jnp.int32)
+    for layer in (0, 2):
+        scales = () if kst_s is None else (kst_s[layer], vst_s[layer])
+        for policy in ("kernels", "reference"):
+            got = dispatch.decode_attention(
+                q, kst, vst, table, lengths, *scales,
+                layer=jnp.int32(layer), policy=policy)
+            want = dispatch.decode_attention(
+                q, kst[layer], vst[layer], table, lengths, *scales,
+                policy=policy)
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32))
+        other = dispatch.decode_attention(
+            q, kst[1], vst[1], table, lengths,
+            *(() if kst_s is None else (kst_s[1], vst_s[1])),
+            policy="kernels")
+        assert not np.allclose(np.asarray(got, np.float32),
+                               np.asarray(other, np.float32))
+
+
 def test_decode_tuned_plan_consumed(tmp_path, monkeypatch):
     """A seeded exact-shape decode plan is picked up by the kernel route
     (lookup counters prove the cache was consulted)."""
@@ -669,6 +711,64 @@ def test_paged_scheduler_int8_greedy_matches_fp32():
         return {r.rid: list(r.out) for r in done}
 
     assert run("int8") == run("")
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+def test_decode_step_writes_only_its_rows_in_every_layer(kv_dtype):
+    """A scheduler decode step writes each slot's new K/V row into every
+    layer's pool (unrolled prefix layer and the scanned stack alike) and
+    leaves every other byte of every pool as it was: the stacked pool is
+    updated in place at the layer index, not rebuilt.  Pools start full
+    of distinct nonzero data.  int8 pools requantize the written page
+    (running-max append), so there the page and its scale may change."""
+    from repro.launch.serve import PagedScheduler, Request
+    cfg = dataclasses.replace(
+        _tiny_cfg("gemma-2b", dispatch="kernels", kv_dtype=kv_dtype),
+        n_layers=5, prefix=(("attn", "mlp"),),
+        pattern=(("attn", "mlp"), ("attn", "mlp")))
+    model = Model(cfg, dt=DtypePolicy(compute=jnp.float32),
+                  opts=ExecOptions(mode="run"))
+    params = model.init(jax.random.key(0))
+    sched = PagedScheduler(model, params, slots=2, max_len=16, page_size=4)
+    leaves, tree = jax.tree.flatten(sched.cache)
+    keys = jax.random.split(jax.random.key(1), len(leaves))
+    sched.cache = jax.tree.unflatten(tree, [
+        jax.random.randint(k, a.shape, 1, 100).astype(a.dtype)
+        if a.dtype == jnp.int8 else
+        (1.0 + jax.random.uniform(k, a.shape)).astype(a.dtype)
+        for k, a in zip(keys, leaves)])
+    r = Request(0, np.arange(1, 7), 4)
+    assert sched.try_admit(r, 0)
+    before = jax.tree.map(lambda a: np.array(a, copy=True), sched.cache)
+    sched.prepare_decode([0])
+    sched.step(np.asarray([r.out[-1], 0], np.int32))
+    after = jax.tree.map(np.asarray, sched.cache)
+
+    # slot 0 writes row lengths[0] of its page; idle slot 1 the trash page
+    length = int(sched.lengths[0])
+    pid, off = int(sched.table[0, length // 4]), length % 4
+    written = [(pid, off), (0, 0)]
+    checked = 0
+    for (path, old), new in zip(jax.tree_util.tree_leaves_with_path(before),
+                                jax.tree.leaves(after)):
+        name, where = path[-1].key, jax.tree_util.keystr(path)
+        pool = name.endswith("pages")
+        lead = old.ndim - (4 if pool else 2)     # 1 for the scanned stack
+        mask = np.ones(old.shape, bool)
+        for p_, o_ in written:
+            at = (slice(None),) * lead + (p_,)
+            if pool and not kv_dtype:
+                at += (slice(None), o_)
+            mask[at] = False
+        np.testing.assert_array_equal(new[mask], old[mask], err_msg=where)
+        if name == "k_pages":
+            per_layer = (-1,) + old.shape[-4:]
+            for ln, lo in zip(new.reshape(per_layer),
+                              old.reshape(per_layer)):
+                assert not np.array_equal(ln[pid, :, off],
+                                          lo[pid, :, off]), where
+            checked += 1
+    assert checked == 3     # the prefix layer's pool and the stack's two
 
 
 def test_int8_scale_lockstep_and_byte_residency():

@@ -135,6 +135,44 @@ def test_prefill_attention_int8_differential(empty_plan_cache):
     assert s[("prefill_attention", "kernel")] == 1
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_prefill_attention_stacked_pool_reads_its_layer(kv_dtype,
+                                                        empty_plan_cache):
+    """The model's layer-stacked (L, P, Hkv, page, hd) pool plus a layer
+    index reads exactly what the same op reads from that layer's pool
+    alone, on the kernel and the reference route; every layer holds
+    distinct, nonzero data (int8 stacks pass that layer's scales)."""
+    from repro.core import quant
+    q, kp, vp, table, starts = _prefill_inputs(4, 2, 16, jnp.bfloat16)
+    kst = jnp.stack([kp.astype(jnp.float32) * (l + 1) + l
+                     for l in range(3)])
+    vst = jnp.stack([vp.astype(jnp.float32) * (l + 1) - l
+                     for l in range(3)])
+    if kv_dtype == "int8":
+        (kst, kst_s), (vst, vst_s) = (quant.quantize_pages(kst),
+                                      quant.quantize_pages(vst))
+    else:
+        kst, vst = kst.astype(jnp.bfloat16), vst.astype(jnp.bfloat16)
+
+    def scales(layer):
+        return () if kv_dtype != "int8" else (kst_s[layer], vst_s[layer])
+
+    for layer in (0, 2):
+        for policy in ("kernels", "reference"):
+            got = dispatch.prefill_attention(
+                q, kst, vst, table, starts, *scales(layer),
+                layer=jnp.int32(layer), policy=policy)
+            want = dispatch.prefill_attention(
+                q, kst[layer], vst[layer], table, starts, *scales(layer),
+                policy=policy)
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32))
+        other = dispatch.prefill_attention(
+            q, kst[1], vst[1], table, starts, *scales(1), policy="kernels")
+        assert not np.allclose(np.asarray(got, np.float32),
+                               np.asarray(other, np.float32))
+
+
 def test_prefill_pages_per_tile_invariant():
     """KV-tile geometry is a pure performance knob: every pages_per_tile
     (incl. non-divisors of n_pages -> padded tail tiles) agrees."""

@@ -14,7 +14,12 @@ the module is imported: only one process may load the TPU library at a
 time, and under pytest-xdist every worker imports every test file.
 ``plan="heuristic"`` keeps a CPU-tuned plan from routing a shape to the
 reference lowering.
+
+The paged serving steps are compiled whole, at the benchmark's GQA widths
+(4 KV heads) and two layers, to check that they update the layer-stacked
+KV pool in place: their temporaries must stay below one layer's pool.
 """
+import dataclasses
 import os
 
 import jax
@@ -22,9 +27,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.archs import ARCHS
+from repro.core.memory import DtypePolicy
 from repro.kernels.attention import (decode_attention, flash_attention,
                                      flash_attention_bwd, prefill_attention)
 from repro.kernels.matmul.ops import matmul, quantized_matmul
+from repro.models.transformer import ExecOptions, Model
 
 H, HKV, HD, PAGE = 32, 32, 128, 64
 D_MODEL, D_FF, VOCAB = 4096, 13440, 92416
@@ -146,3 +154,58 @@ def test_quantized_matmul_compiles(one_chip):
                                 interpret=False)
 
     assert "tpu_custom_call" in _compile(fn, a, w, scale)
+
+
+@pytest.fixture
+def tpu_choices(monkeypatch):
+    """Steer the program's backend-dependent choices to the chip's, as a
+    run there makes them: compiled kernels rather than interpret mode,
+    and tuned plans looked up under the chip's key."""
+    from repro.kernels.attention import ops as attention_ops
+    from repro.kernels.matmul import ops as matmul_ops
+    from repro.tune import cache as plan_cache
+    monkeypatch.setattr(attention_ops, "interpret_default", lambda: False)
+    monkeypatch.setattr(matmul_ops, "interpret_default", lambda: False)
+    monkeypatch.setattr(plan_cache, "_backend_name",
+                        lambda backend=None: backend or "tpu")
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_paged_step_updates_pool_in_place(one_chip, tpu_choices, step):
+    """A paged serving step, compiled for the chip with its cache donated,
+    writes the new K/V rows into the layer-stacked pool in place: its
+    temporaries stay below one layer's K pool.  A layer scan that takes
+    the pool as xs and gives it back as ys copies every layer's pool out
+    of the stack and into a new one, ~1.5 pools of temporaries."""
+    cfg = dataclasses.replace(ARCHS["codeqwen1.5-7b"], n_layers=2,
+                              n_kv_heads=4, dispatch="kernels")
+    model = Model(cfg, dt=DtypePolicy(param=jnp.bfloat16),
+                  opts=ExecOptions(mode="run"))
+    slots, max_len = 8, 4096                # 513 pages of 64
+    n_pages = max_len // PAGE
+
+    def place(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                            tree)
+
+    def i32(*shape):
+        return _spec(one_chip, shape, jnp.int32)
+
+    params = place(jax.eval_shape(model.init, jax.random.key(0)))
+    cache = place(jax.eval_shape(
+        lambda: model.init_paged_cache(slots, max_len, PAGE)))
+    if step == "decode":
+        lowered = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+            params, cache, {"tokens": i32(slots, 1)}, i32(),
+            (i32(slots), i32(slots, n_pages)))
+    else:
+        b = 2                               # chunks batched in one prefill
+        lowered = jax.jit(model.prefill_step_paged,
+                          donate_argnums=(1,)).lower(
+            params, cache, i32(b, PAGE), i32(b), i32(b, n_pages), i32(b))
+    compiled = lowered.compile()
+    pool = cache["stack"][0]["k_pages"]     # (layers, pages, Hkv, page, hd)
+    one_layer = pool.size // pool.shape[0] * pool.dtype.itemsize
+    assert "tpu_custom_call" in compiled.as_text()
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < one_layer, (temps, one_layer)
