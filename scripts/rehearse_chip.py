@@ -84,11 +84,11 @@ def _report(name, compiled) -> int:
     return need
 
 
-def _serve_specs(model, size, n_chunks, sharding_of):
+def _serve_specs(model, size, n_chunks, sharding_of, total_pages=None):
     key = jax.random.key(0)
     params = _place(jax.eval_shape(model.init, key), sharding_of)
     cache = _place(jax.eval_shape(lambda: model.init_paged_cache(
-        size.slots, size.max_len, size.page)), sharding_of)
+        size.slots, size.max_len, size.page, total_pages)), sharding_of)
     n_pages = size.max_len // size.page
     i32 = jnp.int32
 
@@ -159,17 +159,35 @@ def rehearse_train(topo, tsize=chip_smoke.TrainSize()) -> None:
              chip_smoke.TRAIN_OPS)
 
 
-def rehearse_four_chips(topo, tp: int = 4) -> None:
+# the benchmark's tp4 cell (benchmarks/chip/configs/codeqwen1.5-7b.tp4.json):
+# CodeQwen1.5-7B whole, 32 layers and the published 4 KV heads, over 4 chips
+TP4 = chip_smoke.ServeSize(layers=32, slots=32, max_len=8192, page=64,
+                           requests=8)
+TP4_POOL_PAGES = 4097
+
+
+def rehearse_four_chips(topo, tp: int = 4, size=TP4) -> None:
+    """The whole model at tensor parallel ``tp``: the sharded creation of
+    params and pool, the decode step and every prefill width, per chip."""
+    import dataclasses
     from repro.launch.mesh import make_mesh
     from repro.runtime import tp as tp_mod
-    cfg = chip_smoke._cut(get_arch(chip_smoke.ARCH),
-                          chip_smoke.ServeSize().layers)
-    size = chip_smoke.ServeSize()
+    cfg = dataclasses.replace(
+        chip_smoke._cut(get_arch(chip_smoke.ARCH), size.layers),
+        n_kv_heads=4)
     model = chip_smoke.Model(cfg,
                              dt=chip_smoke.DtypePolicy(param=jnp.bfloat16),
                              opts=chip_smoke.ExecOptions(mode="run"))
     mesh = make_mesh((tp,), ("model",), devices=topo.devices[:tp])
     replicated = NamedSharding(mesh, jax.sharding.PartitionSpec())
+    key = jax.random.key(0)
+    _report(f"tp={tp} params drawn in their shardings",
+            tp_mod.params_creator(model, mesh).lower(jax.ShapeDtypeStruct(
+                key.shape, key.dtype, sharding=replicated)).compile())
+    _report(f"tp={tp} pool of {TP4_POOL_PAGES} pages in its shardings",
+            tp_mod.paged_cache_creator(model, mesh, size.slots,
+                                       size.max_len, size.page,
+                                       TP4_POOL_PAGES).lower().compile())
 
     def sharding_of(tree):
         if tree is None:
@@ -180,21 +198,26 @@ def rehearse_four_chips(topo, tp: int = 4) -> None:
             specs = tp_mod.cache_pspecs(tree, cfg, tp)
         return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
 
-    decode_args, prefill_args = _serve_specs(model, size, size.requests,
-                                             sharding_of)
     decode, prefill = tp_mod.sharded_paged_fns(model, mesh)
-    _compile(f"tp={tp} decode step",
+    decode_args, _ = _serve_specs(model, size, 1, sharding_of,
+                                  TP4_POOL_PAGES)
+    _compile(f"tp={tp} decode step ({size.slots} slots)",
              lambda: jax.jit(decode, donate_argnums=(1,))
              .lower(*decode_args), DECODE_OPS)
-    _compile(f"tp={tp} prefill step ({size.requests} chunks)",
-             lambda: jax.jit(prefill, donate_argnums=(1,))
-             .lower(*prefill_args), PREFILL_OPS)
+    for width in range(1, size.requests + 1):
+        _, prefill_args = _serve_specs(model, size, width, sharding_of,
+                                       TP4_POOL_PAGES)
+        _compile(f"tp={tp} prefill step ({width} chunks)",
+                 lambda: jax.jit(prefill, donate_argnums=(1,))
+                 .lower(*prefill_args), PREFILL_OPS)
 
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--four-chips", action="store_true",
-                    help="compile the tp=4 serving steps on a 2x2 mesh")
+                    help="compile CodeQwen1.5-7B whole at tp=4 on a 2x2 "
+                         "mesh: sharded creation, decode, every prefill "
+                         "width")
     args = ap.parse_args(argv)
     from jax.experimental import topologies
     topo = topologies.get_topology_desc(platform="tpu",

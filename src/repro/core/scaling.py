@@ -134,16 +134,28 @@ class TilePlanner:
                     if b <= round_up(dim, mxu) and not dim % min(b, dim)] \
                 or [dim]
 
-        plans: List[TilePlan] = []
-        for bm in tiles(m):
-            for bn in tiles(n):
-                for bk in tiles(k):
-                    try:
-                        plans.append(self.plan_from_tiles(
-                            m, n, k, bm, bn, bk,
-                            in_bytes=in_bytes, acc_bytes=acc_bytes))
-                    except ValueError:
-                        continue
+        def enumerate_tiles(rows: List[int]) -> List[TilePlan]:
+            plans: List[TilePlan] = []
+            for bm in rows:
+                for bn in tiles(n):
+                    for bk in tiles(k):
+                        try:
+                            plans.append(self.plan_from_tiles(
+                                m, n, k, bm, bn, bk,
+                                in_bytes=in_bytes, acc_bytes=acc_bytes))
+                        except ValueError:
+                            continue
+            return plans
+
+        plans = enumerate_tiles(tiles(m))
+        if not plans:
+            # rows that no candidate tiles finely enough (5 or 7 prefill
+            # chunks of 64) against a whole-dim 3360 block overflow VMEM;
+            # a smaller row block whose height is a multiple of the
+            # dtype's packed sublanes is a legal TPU block
+            align = self.hw.sublane * max(1, 4 // in_bytes)
+            plans = enumerate_tiles([b for b in range(align, m, align)
+                                     if not m % b])
         plans.sort(key=_plan_order_key, reverse=True)
         return plans
 

@@ -448,9 +448,13 @@ def call(name: str, *args, statics: Optional[Dict[str, Any]] = None,
                 f"op {name!r} declares no tp contract {tp!r} "
                 f"(has: {sorted(spec.tp or {})}); sharded serving cannot "
                 "complete this call inside the mapped region")
+        # named, so that a profile or the HLO's op names show them
         if contract.collective == "psum":
-            out = jax.lax.psum(out, _TP_AXIS)
+            with jax.named_scope("tp.psum"):
+                out = jax.lax.psum(out, _TP_AXIS)
         elif contract.collective == "all_gather":
-            out = jax.lax.all_gather(out, _TP_AXIS,
-                                     axis=contract.gather_axis, tiled=True)
+            with jax.named_scope("tp.all_gather"):
+                out = jax.lax.all_gather(out, _TP_AXIS,
+                                         axis=contract.gather_axis,
+                                         tiled=True)
     return out

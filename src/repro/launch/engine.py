@@ -152,10 +152,11 @@ class StepExecutor:
                 last = np.asarray([min(states[s].ln, st + page) - 1 - st
                                    for s, st in chunks], np.int32)
             with tracing.span("prefill.launch"):
-                logits, sched.cache = sched._prefill(
-                    sched.params, sched.cache, tracing.to_device(toks),
-                    tracing.to_device(starts), tracing.to_device(tables),
-                    tracing.to_device(last))
+                rest = (tracing.to_device(toks), tracing.to_device(starts),
+                        tracing.to_device(tables), tracing.to_device(last))
+                logits, sched.cache = sched._prefill(sched.params,
+                                                     sched.cache, *rest)
+                sched.count_collectives("prefill", *rest)
             with tracing.span("prefill.wait"):
                 logits = tracing.to_host(logits)
         self.t_prefill += sp.ns_of("prefill.launch", "prefill.wait") * 1e-9
@@ -351,7 +352,8 @@ class ContinuousEngine:
     def step(self) -> bool:
         """One engine iteration; returns False once fully drained.  Each
         call leaves one record in ``launch/tracing.py``."""
-        with tracing.iteration(self.trace_id, self.step_calls):
+        with tracing.iteration(self.trace_id, self.step_calls,
+                               tp=self.sched.tp):
             self.step_calls += 1
             return self._step()
 

@@ -314,8 +314,24 @@ class PagedScheduler:
         self.n_slot_pages = -(-max_len // self.page)
         total = total_pages or 1 + slots * self.n_slot_pages
         self.alloc = PageAllocator(total)
-        self.cache = model.init_paged_cache(slots, max_len, self.page,
-                                            total_pages=total)
+        # ---- tensor parallelism (runtime/tp.py) ----
+        # a mesh shards params + KV pools over its "model" axis and swaps
+        # the step fns for shard_map'd twins; the scheduler's host-side
+        # page metadata (tables, lengths, allocator, trie) is device-free
+        # and identical across shards, so nothing else changes
+        self.mesh = mesh
+        self.tp = int(mesh.shape["model"]) if mesh is not None else 1
+        if mesh is not None:
+            from ..runtime import tp as tp_mod
+            err = tp_mod.tp_error(model.cfg, self.tp)
+            if err:
+                raise ValueError(err)
+            # each device allocates only its own KV heads' pools
+            self.cache = tp_mod.paged_cache_creator(
+                model, mesh, slots, max_len, self.page, total)()
+        else:
+            self.cache = model.init_paged_cache(slots, max_len, self.page,
+                                                total_pages=total)
         # quantized pools carry per-page scale rows; their lifecycle is
         # slaved to the allocator via on_alloc (reset-on-reuse)
         self._has_scales = any(
@@ -354,24 +370,15 @@ class PagedScheduler:
         # page; its copy-on-write page is reserved at admission so the
         # reserve-on-admit contract (never stall mid-decode) still holds
         self.cow_stash: List[List[int]] = [[] for _ in range(slots)]
-        # ---- tensor parallelism (runtime/tp.py) ----
-        # a mesh shards params + KV pools over its "model" axis and swaps
-        # the step fns for shard_map'd twins; the scheduler's host-side
-        # page metadata (tables, lengths, allocator, trie) is device-free
-        # and identical across shards, so nothing else changes
-        self.mesh = mesh
-        self.tp = int(mesh.shape["model"]) if mesh is not None else 1
+        # bytes per device the collectives of one step move, by step kind
+        # and argument shapes (collective_bytes)
+        self._collective_bytes: Dict[tuple, int] = {}
         if mesh is not None:
-            from ..runtime import tp as tp_mod
-            err = tp_mod.tp_error(model.cfg, self.tp)
-            if err:
-                raise ValueError(err)
+            # params drawn in place (tp.init_params_sharded) are kept as
+            # they are; others are placed shard by shard
             self.params = tp_mod.shard_tree(
                 params, tp_mod.param_pspecs(params, model.cfg, self.tp),
                 mesh)
-            self.cache = tp_mod.shard_tree(
-                self.cache, tp_mod.cache_pspecs(self.cache, model.cfg,
-                                                self.tp), mesh)
             dec, pre = tp_mod.sharded_paged_fns(model, mesh)
             self._decode = jax.jit(dec, donate_argnums=(1,))
             self._prefill = jax.jit(pre, donate_argnums=(1,))
@@ -720,15 +727,39 @@ class PagedScheduler:
         lengths, table = view if view is not None \
             else (self.lengths, self.table)
         with tracing.span("decode.launch"):
-            logits, self.cache = self._decode(
-                self.params, self.cache, self._feed_batch(tokens, lengths),
-                tracing.to_device(np.int32(0)),
-                (tracing.to_device(lengths), tracing.to_device(table)))
+            rest = (self._feed_batch(tokens, lengths),
+                    tracing.to_device(np.int32(0)),
+                    (tracing.to_device(lengths), tracing.to_device(table)))
+            logits, self.cache = self._decode(self.params, self.cache, *rest)
+            self.count_collectives("decode", *rest)
             self.decode_steps += 1
             self.decode_tokens += int(np.count_nonzero(lengths))
             nxt = jnp.argmax(logits, axis=-1)
         with tracing.span("decode.wait"):
             return tracing.to_host(nxt)
+
+    def collective_bytes(self, kind: str, *rest) -> int:
+        """Bytes per device that the collectives of one ``kind`` step
+        (``"decode"`` | ``"prefill"``) move, read off the compiled step's
+        HLO (``tp.collective_bytes``); ``rest`` are the step's arguments
+        after params and cache.  Read once per argument shape, from the
+        executable the step's own call compiled; 0 without a mesh."""
+        if self.mesh is None:
+            return 0
+        key = (kind,) + tuple(np.shape(a) for a in jax.tree.leaves(rest))
+        if key not in self._collective_bytes:
+            from ..runtime import tp as tp_mod
+            fn = self._decode if kind == "decode" else self._prefill
+            hlo = fn.lower(self.params, self.cache, *rest).compile().as_text()
+            self._collective_bytes[key] = tp_mod.collective_bytes(hlo,
+                                                                  self.tp)
+        return self._collective_bytes[key]
+
+    def count_collectives(self, kind: str, *rest) -> None:
+        """Count one step's ``tp_collective_bytes`` under a mesh."""
+        if self.mesh is not None:
+            tracing.count("tp_collective_bytes",
+                          self.collective_bytes(kind, *rest))
 
     # --------------------------------------------------- speculative decoding
     def draft_for(self, drafter, slots: List[int]) -> Dict[int, List[int]]:
@@ -1043,16 +1074,20 @@ def main(argv=None):
         raise SystemExit("serving demo drives token-mode archs")
     model = Model(cfg, dt=DtypePolicy(param=jnp.bfloat16),
                   opts=ExecOptions(mode="run"))
-    params = model.init(jax.random.key(0))
     mesh = None
     if args.mesh:
         if args.cache != "paged":
             raise SystemExit("--mesh requires --cache paged")
+        from ..runtime.tp import init_params_sharded
         from .mesh import make_serving_mesh
         mesh = make_serving_mesh(args.mesh)
         print(f"[mesh] model={args.mesh} "
               f"devices={len(jax.devices())} visible "
               f"(backend={jax.default_backend()})")
+        # drawn shard by shard: no device holds the whole model
+        params = init_params_sharded(model, jax.random.key(0), mesh)
+    else:
+        params = model.init(jax.random.key(0))
     drafter = None
     if args.speculate:
         if args.cache != "paged":

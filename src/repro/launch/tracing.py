@@ -54,11 +54,13 @@ MAX_ITERATIONS = 1 << 15
 
 @dataclass
 class Iteration:
-    """What one engine iteration recorded."""
+    """What one engine iteration recorded; ``tp`` is the degree of the
+    tensor-parallel mesh it ran on (1 without one)."""
     engine: int
     step: int
     spans: Dict[str, int] = field(default_factory=dict)     # ns
     counters: Dict[str, int] = field(default_factory=dict)
+    tp: int = 1
 
 
 @dataclass
@@ -130,10 +132,11 @@ def new_engine_id() -> int:
 
 
 @contextlib.contextmanager
-def iteration(engine: int, step: int) -> Iterator[Iteration]:
-    """Open the record of engine ``engine``'s iteration ``step``; it is
-    kept when the block ends, also when the block raises."""
-    rec = Iteration(engine, step)
+def iteration(engine: int, step: int, tp: int = 1) -> Iterator[Iteration]:
+    """Open the record of engine ``engine``'s iteration ``step`` on a
+    mesh of tensor-parallel degree ``tp``; it is kept when the block
+    ends, also when the block raises."""
+    rec = Iteration(engine, step, tp=tp)
     prev, _open[0] = _open[0], rec
     try:
         with jax.profiler.StepTraceAnnotation(PREFIX + "engine",

@@ -140,6 +140,17 @@ def test_tileplanner_whole_dim_block_for_unaligned_dims(shape):
         assert b % 128 == 0 or b == dim
 
 
+@pytest.mark.parametrize("m", [320, 448])
+def test_tileplanner_splits_rows_when_whole_blocks_overflow(m):
+    """5 or 7 prefill chunks of 64 rows against a tp=4 MLP shard (3360
+    columns, one whole-dim block): no 128-multiple divides the rows and
+    one (m, 3360) f32 block pair overflows VMEM, so the rows split into
+    blocks of a multiple of bf16's 16 packed sublanes."""
+    plan = TilePlanner().plan_matmul(m, 3360, 4096)
+    assert plan.bn == 3360 and not m % plan.bm and plan.bm % 16 == 0
+    assert plan.bm < m
+
+
 def test_matmul_kernel_on_whole_dim_block():
     from repro.kernels import dispatch
     x = jax.random.normal(jax.random.key(0), (8, 64), jnp.float32)

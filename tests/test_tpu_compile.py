@@ -104,6 +104,34 @@ def test_prefill_attention_compiles(one_chip, dtype):
                                          *scales)
 
 
+@pytest.mark.parametrize("op", ["decode", "prefill"])
+def test_tp4_shard_kernels_compile(one_chip, op):
+    """One shard of CodeQwen1.5-7B served whole at tensor parallel 4: one
+    of the 4 KV heads and its 8 query heads, read from the layer-stacked
+    pool of all 32 layers at the serve cells' 4097 pages, at a layer
+    index, as the shard_map'd steps call the kernels."""
+    slots, n_pages, layers, heads = 32, 8192 // PAGE, 32, H // 4
+    pool = _spec(one_chip, (layers, 1 + slots * n_pages, 1, PAGE, HD),
+                 jnp.bfloat16)
+    layer = _spec(one_chip, (), jnp.int32)
+    if op == "decode":
+        b, q = slots, _spec(one_chip, (slots, heads, HD), jnp.bfloat16)
+        attend = decode_attention
+    else:
+        b = 8                               # chunks of the 512-token budget
+        q = _spec(one_chip, (b, PAGE, heads, HD), jnp.bfloat16)
+        attend = prefill_attention
+    table = _spec(one_chip, (b, n_pages), jnp.int32)
+    rows = _spec(one_chip, (b,), jnp.int32)   # lengths or chunk starts
+
+    def fn(q, kp, vp, table, rows, layer):
+        return attend(q, kp, vp, table, rows, layer=layer,
+                      plan="heuristic", interpret=False)
+
+    assert "tpu_custom_call" in _compile(fn, q, pool, pool, table, rows,
+                                         layer)
+
+
 def test_flash_forward_with_residuals_compiles(one_chip):
     qkv = _spec(one_chip, (1, H, MAX_LEN, HD), jnp.bfloat16)
 
@@ -131,9 +159,10 @@ def test_flash_backward_compiles(one_chip):
 @pytest.mark.parametrize("m,k,n", [(256, D_MODEL, D_FF),
                                    (SLOTS, D_MODEL, VOCAB),
                                    (SLOTS, D_MODEL, D_FF // 4),
-                                   (256, D_FF // 4, D_MODEL)],
+                                   (256, D_FF // 4, D_MODEL),
+                                   (5 * PAGE, D_MODEL, D_FF // 4)],
                          ids=["mlp_up", "decode_logits", "tp4_mlp_up",
-                              "tp4_mlp_down"])
+                              "tp4_mlp_down", "tp4_mlp_up_5_chunks"])
 def test_matmul_compiles(one_chip, m, k, n):
     a = _spec(one_chip, (m, k), jnp.bfloat16)
     b = _spec(one_chip, (k, n), jnp.bfloat16)
