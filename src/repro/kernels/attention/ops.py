@@ -24,7 +24,9 @@ from .. import registry
 from ..common import interpret_default
 from . import ref
 from .backward import flash_attention_bwd_pallas
-from .decode import decode_attention_pallas, heuristic_pages_per_tile
+from .decode import (decode_attention_pallas, heuristic_pages_per_tile,
+                     decode_work as _decode_work,
+                     decode_work_count as _decode_work_count)
 from .flash import flash_attention_pallas
 from .prefill import prefill_attention_pallas
 
@@ -143,7 +145,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
 @functools.partial(jax.jit, static_argnames=("window", "level",
                                              "pages_per_tile", "interpret"))
 def _decode_attention(q, k_pages, v_pages, table, lengths, k_scale,
-                      v_scale, layer, *, window: int, level: Level,
+                      v_scale, layer, work, *, window: int, level: Level,
                       pages_per_tile: int, interpret: bool) -> jax.Array:
     if level in (Level.T0_NAIVE, Level.T1_PIPELINED):
         return ref.decode_attention_ref(q, k_pages, v_pages, table, lengths,
@@ -153,7 +155,30 @@ def _decode_attention(q, k_pages, v_pages, table, lengths, k_scale,
                                    k_scale, v_scale, layer=layer,
                                    window=window,
                                    pages_per_tile=pages_per_tile,
-                                   interpret=interpret)
+                                   work=work, interpret=interpret)
+
+
+def decode_work(lengths: jax.Array, table: jax.Array, *, page: int,
+                window: int = 0):
+    """The decode kernel's work list (``decode.decode_work``) at the
+    default KV-tile geometry: the live (row, KV tile) pairs of ``lengths``
+    and the page ids each reads.  Every layer of a decode step reads the
+    same lengths and table, so the step builds it once and passes it to
+    each layer's ``decode_attention(work=)``; a call at another geometry
+    (a tuned ``pages_per_tile``) builds its own."""
+    return _decode_work(lengths, table, page=page, window=window,
+                        pages_per_tile=heuristic_pages_per_tile(
+                            table.shape[1], page))
+
+
+def decode_work_count(lengths, n_pages: int, page: int,
+                      window: int = 0) -> int:
+    """The items of :func:`decode_work`, counted on the host from the
+    same lengths: the kernel's grid steps per kv head at the default
+    geometry."""
+    return _decode_work_count(lengths, n_pages, page,
+                              heuristic_pages_per_tile(n_pages, page),
+                              window=window)
 
 
 def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -165,6 +190,7 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      level: Level = Level.T3_REPLICATED,
                      pages_per_tile: Optional[int] = None,
                      plan: Union[str, dict, None] = "heuristic",
+                     work=None,
                      interpret: Optional[bool] = None) -> jax.Array:
     """Ragged decode attention over a paged KV cache.
 
@@ -185,6 +211,10 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     miss), or a tuned kwargs dict (``pages_per_tile``, optional ``level``;
     ``page_size`` / ``prefetch_depth`` entries are layout / feasibility
     knobs and are ignored at call time).
+
+    ``work`` is :func:`decode_work` of the same ``lengths``, ``table`` and
+    ``window``, shared by the layers of one step; without it the kernel
+    route builds its own.
     """
     if interpret is None:
         interpret = interpret_default()
@@ -199,7 +229,7 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if pages_per_tile is None:
         pages_per_tile = heuristic_pages_per_tile(n_pages, page)
     return _decode_attention(q, k_pages, v_pages, table, lengths,
-                             k_scale, v_scale, layer, window=window,
+                             k_scale, v_scale, layer, work, window=window,
                              level=level,
                              pages_per_tile=int(pages_per_tile),
                              interpret=interpret)
@@ -575,7 +605,8 @@ def _paged_pools_ok(q, k_pages, v_pages, k_scale, v_scale) -> bool:
 
 
 def _decode_eligible(st, q, k_pages, v_pages, table, lengths,
-                     k_scale=None, v_scale=None, layer=None) -> bool:
+                     k_scale=None, v_scale=None, layer=None,
+                     work=None) -> bool:
     if st["softcap"] > 0:
         return False
     if q.shape[1] % k_pages.shape[-3]:
@@ -584,7 +615,7 @@ def _decode_eligible(st, q, k_pages, v_pages, table, lengths,
 
 
 def _decode_plan_shape(st, q, k_pages, v_pages, table, lengths,
-                       k_scale=None, v_scale=None, layer=None):
+                       k_scale=None, v_scale=None, layer=None, work=None):
     return (q.shape[0], q.shape[1], table.shape[1], k_pages.shape[-2],
             q.shape[2])
 
@@ -596,7 +627,7 @@ def _paged_plan_dtype(st, q, k_pages, *rest):
 
 
 def _decode_ref_lowering(ctx, q, k_pages, v_pages, table, lengths,
-                         k_scale=None, v_scale=None, layer=None):
+                         k_scale=None, v_scale=None, layer=None, work=None):
     kw = ctx.kw
     return decode_attention_reference(
         q, k_pages, v_pages, table, lengths, k_scale, v_scale, layer,
@@ -605,11 +636,13 @@ def _decode_ref_lowering(ctx, q, k_pages, v_pages, table, lengths,
 
 
 def _decode_kernel_lowering(ctx, q, k_pages, v_pages, table, lengths,
-                            k_scale=None, v_scale=None, layer=None):
+                            k_scale=None, v_scale=None, layer=None,
+                            work=None):
     kw = ctx.kw
     out = decode_attention(q, k_pages, v_pages, table, lengths,
                            k_scale, v_scale, layer=layer,
-                           window=kw["window"], plan=ctx.ops_plan())
+                           window=kw["window"], plan=ctx.ops_plan(),
+                           work=work)
     return out.astype(kw["out_dtype"])
 
 
@@ -852,12 +885,12 @@ registry.register(registry.OpSpec(
     tp={
         # heads are the sharded axis: q (B, H, hd) on dim 1, K/V pools
         # ([L,] P, Hkv, page, hd) on their Hkv dim (-3), per-page scales
-        # (P, Hkv) on dim 1; table/lengths and the layer index are host
-        # metadata, replicated. Each shard attends its own heads against
-        # its own pool slice, then the per-shard (B, H/tp, hd) outputs
-        # all-gather back to full heads on dim 1.
+        # (P, Hkv) on dim 1; table/lengths, the layer index and the work
+        # list are host metadata, replicated. Each shard attends its own
+        # heads against its own pool slice, then the per-shard (B, H/tp,
+        # hd) outputs all-gather back to full heads on dim 1.
         "heads": registry.TPContract(
-            in_axes=(1, -3, -3, None, None, 1, 1, None),
+            in_axes=(1, -3, -3, None, None, 1, 1, None, None),
             collective="all_gather",
             gather_axis=1,
         ),

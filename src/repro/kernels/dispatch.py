@@ -218,6 +218,7 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      window: int = 0, softcap: float = 0.0,
                      accum_dtype: Any = jnp.float32,
                      out_dtype: Any = None,
+                     work=None,
                      policy: PolicyLike = None) -> jax.Array:
     """Ragged decode attention over a paged KV cache — the serving hot path.
 
@@ -231,10 +232,13 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     dequantizes page tiles at load time, the reference at gather time.
     Returns (B, H, hd) in ``out_dtype`` (default q's dtype).  Inference
     only — no custom VJP; the kernel route consults the tuned-plan cache
-    for KV-tile geometry (keyed on the POOL dtype).
+    for KV-tile geometry (keyed on the POOL dtype).  ``work`` is
+    :func:`decode_work` of the same lengths, table and window, built once
+    for every layer of a step; the reference route ignores it.
     """
     out_dtype = q.dtype if out_dtype is None else out_dtype
-    args = (q, k_pages, v_pages, table, lengths, k_scale, v_scale, layer)
+    args = (q, k_pages, v_pages, table, lengths, k_scale, v_scale, layer,
+            work)
     # "heads" is the op's single sharding contract: q heads and KV pools
     # device-local, output all-gathered back to full head width so the
     # (replicated) out-projection sees every head.  Inert unsharded.
@@ -243,6 +247,23 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         statics=dict(window=int(window), softcap=float(softcap),
                      accum_dtype=accum_dtype, out_dtype=out_dtype),
         policy=policy, tp="heads")
+
+
+def decode_work(lengths: jax.Array, table: jax.Array, *, page: int,
+                window: int = 0):
+    """The decode kernel's work list for one step's ``lengths`` and
+    ``table``: the live (row, KV tile) pairs its grid walks and the page
+    ids each reads (``attention.ops.decode_work``)."""
+    from .attention.ops import decode_work as _decode_work
+    return _decode_work(lengths, table, page=page, window=window)
+
+
+def decode_work_count(lengths, n_pages: int, page: int,
+                      window: int = 0) -> int:
+    """The items of :func:`decode_work`, counted on the host: the decode
+    kernel's grid steps per kv head for these lengths."""
+    from .attention.ops import decode_work_count as _count
+    return _count(lengths, n_pages, page, window=window)
 
 
 def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,

@@ -34,6 +34,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import dispatch
 from . import tracing
 from .loadgen import ArrivalQueue, Request
 from .metrics import ServeMetrics
@@ -178,6 +179,13 @@ class StepExecutor:
                 lengths = np.where(mask, sched.lengths, 0).astype(np.int32)
                 table = np.where(mask[:, None], sched.table,
                                  0).astype(np.int32)
+                # one layer's grid steps per kv head in the decode kernel
+                # (its default tile geometry): the decoding rows attend to
+                # their cached tokens and the new one, every other row to
+                # nothing
+                tracing.count("decode_tiles", dispatch.decode_work_count(
+                    np.where(mask, lengths + 1, 0), sched.n_slot_pages,
+                    sched.page, window=sched.window))
             nxt = sched.step(cur, view=(lengths, table))
         self.t_decode += sp.ns_of("decode.launch", "decode.wait") * 1e-9
         return nxt

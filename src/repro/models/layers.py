@@ -18,7 +18,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -324,6 +324,20 @@ def _layer_scales(k_scale, v_scale, layer):
     return k_scale[layer], v_scale[layer]
 
 
+def paged_decode_view(lengths: jax.Array, table: jax.Array, page: int,
+                      windows) -> Tuple[jax.Array, Dict[int, Any]]:
+    """What every attention layer of one paged decode step shares, built
+    once a step outside the layer scan: each row's KV length with its new
+    token, and the decode kernel's work list (``dispatch.decode_work``)
+    for each window in ``windows``.  A ride-along row, whose token lands
+    on the trash page (physical page 0), attends to nothing: at KV length
+    0 it is no work for the kernel and its (discarded) output is zero."""
+    pid = table[jnp.arange(lengths.shape[0]), lengths // page]
+    kv_len = jnp.where(pid > 0, lengths + 1, 0)
+    return kv_len, {w: dispatch.decode_work(kv_len, table, page=page,
+                                            window=w) for w in windows}
+
+
 def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
                            lengths: jax.Array, table: jax.Array,
                            k_pages: jax.Array, v_pages: jax.Array,
@@ -331,7 +345,8 @@ def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
                            positions_override: Optional[jax.Array] = None,
-                           layer: Optional[jax.Array] = None
+                           layer: Optional[jax.Array] = None,
+                           view: Optional[Tuple[jax.Array, Dict]] = None
                            ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                       Optional[jax.Array],
                                       Optional[jax.Array]]:
@@ -339,7 +354,8 @@ def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
 
     x: (B, 1, d).  lengths: (B,) int32 tokens already cached per slot —
     the new token lands at position ``lengths[b]`` (the scheduler must
-    have a page allocated there; inactive slots point at the trash page).
+    have a page allocated there; inactive slots point at the trash page,
+    and a row whose token lands there attends to nothing).
     table: (B, n_pages) int32 logical->physical page ids into the shared
     (P, Hkv, page, hd) pools.  int8 pools additionally carry ``k_scale`` /
     ``v_scale`` (P, Hkv) f32: the append runs the running-max requantize
@@ -348,6 +364,8 @@ def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
     layer-stacked (L, P, Hkv, page, hd) leaves: the token rows are written
     in place at ``[layer, page, head, offset]`` and the op reads that layer
     out of the stack, so no layer's pool is ever copied.
+    ``view`` is the step's :func:`paged_decode_view`; without it the
+    layer builds its own.
     Returns (out (B,1,d), k_pages, v_pages, k_scale, v_scale).
     """
     b = x.shape[0]
@@ -379,11 +397,14 @@ def attention_decode_paged(p: Params, s: AttnSpec, x: jax.Array,
         v_pages = v_pages.at[at].set(v[:, 0].astype(v_pages.dtype))
     # GQA grouping happens inside the decode kernel/reference, so the
     # pools stay at Hkv heads end-to-end (no expanded copy in HBM)
+    if view is None:
+        view = paged_decode_view(lengths, table, page, (s.window,))
+    kv_len, work = view
     out = dispatch.decode_attention(
-        q[:, 0], k_pages, v_pages, table, lengths + 1,
+        q[:, 0], k_pages, v_pages, table, kv_len,
         *_layer_scales(k_scale, v_scale, layer), layer=layer,
         window=s.window, softcap=s.softcap, accum_dtype=dt.accum,
-        out_dtype=dt.compute, policy=s.dispatch)
+        out_dtype=dt.compute, work=work[s.window], policy=s.dispatch)
     return (_out_proj(p, s, out[:, None], dt), k_pages, v_pages,
             k_scale, v_scale)
 

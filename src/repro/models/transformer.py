@@ -222,8 +222,9 @@ def layer_decode(p: Params, cfg: ArchConfig, kind: LayerKind, x: jax.Array,
                  paged: Optional[Tuple[jax.Array, jax.Array]] = None,
                  layer: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One decode token through one layer.  ``paged`` = (lengths, table)
-    switches attention layers to the paged-KV ragged path (``pos`` is then
+    """One decode token through one layer.  ``paged`` = (lengths, table,
+    view), ``view`` the step's ``layers.paged_decode_view``, switches
+    attention layers to the paged-KV ragged path (``pos`` is then
     ignored — each slot decodes at its own length); recurrent mixers and
     FFNs are cache-layout-agnostic and run unchanged either way.
     ``layer`` marks the page pools as the layer-stacked leaves of the
@@ -234,12 +235,13 @@ def layer_decode(p: Params, cfg: ArchConfig, kind: LayerKind, x: jax.Array,
     if mixer in ("attn", "swa"):
         spec = _attn_spec(cfg, mixer)
         if paged is not None:
-            lengths, table = paged
+            lengths, table, view = paged
             h, kp, vp, ks, vs = layers.attention_decode_paged(
                 p["attn"], spec, h, lengths, table,
                 cache["k_pages"], cache["v_pages"], dt,
                 cache.get("k_scale"), cache.get("v_scale"),
-                positions_override=positions_override, layer=layer)
+                positions_override=positions_override, layer=layer,
+                view=view)
             new_cache["k_pages"], new_cache["v_pages"] = kp, vp
             if ks is not None:
                 new_cache["k_scale"], new_cache["v_scale"] = ks, vs
@@ -675,6 +677,8 @@ class Model:
         cfg, dt, lay, opts = self.cfg, self.dt, self.layout, self.opts
         x = self._embed(params, batch)          # (B, 1, d)
         pos_override = batch.get("positions") if cfg.mrope_sections else None
+        if paged is not None:
+            paged = (*paged, self._paged_decode_view(cache, *paged))
 
         new_cache = {"prefix": [], "stack": [], "tail": []}
         for p, kind, c in zip(params["prefix"], lay.prefix, cache["prefix"]):
@@ -697,6 +701,23 @@ class Model:
 
         logits = self._logits(params, x)[:, 0]
         return logits, new_cache
+
+    def _paged_decode_view(self, cache, lengths, table):
+        """The paged decode view every attention layer of the step reads
+        (``layers.paged_decode_view``), built once before the layer scan:
+        the layers share the lengths and table, so the decode kernel's
+        work list is the same for every layer of one window."""
+        lay = self.layout
+        mixers = {m for m, _ in (*lay.prefix, *lay.period, *lay.tail)
+                  if m in ("attn", "swa")}
+        if not mixers:
+            return None
+        page = next(c["k_pages"].shape[-2]
+                    for part in ("prefix", "stack", "tail")
+                    for c in cache[part] if "k_pages" in c)
+        return layers.paged_decode_view(
+            lengths, table, page,
+            sorted({_attn_spec(self.cfg, m).window for m in mixers}))
 
     # ------------------------------ paged serving ---------------------
     def init_paged_cache(self, slots: int, max_len: int, page_size: int,

@@ -234,6 +234,130 @@ def test_decode_attention_stacked_pool_reads_its_layer(kv_dtype,
                                np.asarray(other, np.float32))
 
 
+# the work-list grid's edges: 8-token pages, 2 pages to a 16-token KV
+# tile, 6 pages (48 tokens) a slot
+PAGE_W, PPT_W, NP_W = 8, 2, 6
+EDGE_LENGTHS = (0, 1, PAGE_W - 1, PAGE_W, PPT_W * PAGE_W,
+                PPT_W * PAGE_W + 1, PAGE_W * NP_W)
+
+
+@pytest.mark.parametrize("case", ["ragged_edges", "one_live_row", "window",
+                                  "int8", "stacked_layer"])
+def test_decode_work_grid_matches_reference(case, empty_plan_cache):
+    """The kernel's grid walks only the live (row, tile) pairs: at every
+    length edge (empty, one token, page and tile boundaries +/- 1, full),
+    with all rows idle but one, under a window, on int8 pools and on a
+    stacked pool read at a nonzero layer, it agrees with the reference
+    route, and rows of length 0 come out exactly zero."""
+    from repro.kernels.attention import decode_attention as decode_op
+    slots = len(EDGE_LENGTHS)
+    q, kp, vp, table = _paged_inputs(4, 2, 16, jnp.float32, slots=slots,
+                                     page=PAGE_W, n_pages=NP_W)
+    lengths = EDGE_LENGTHS
+    if case == "one_live_row":
+        lengths = (0,) * (slots - 2) + (PPT_W * PAGE_W + 5, 0)
+    window = PAGE_W + 3 if case == "window" else 0
+    scales, layer = (), None
+    if case == "int8":
+        kp, ks, vp, vs = _quantized_pools(kp, vp)
+        scales = (ks, vs)
+    if case == "stacked_layer":
+        kp, _, vp, _ = _stacked_pools(kp, vp, "f32")
+        layer = jnp.int32(2)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    got = decode_op(q, kp, vp, table, lengths, *scales, layer=layer,
+                    window=window, pages_per_tile=PPT_W, plan="heuristic")
+    want = dispatch.decode_attention(q, kp, vp, table, lengths, *scales,
+                                     layer=layer, window=window,
+                                     policy="reference")
+    _assert_close(got, want, "float32")
+    idle = np.asarray(lengths) == 0
+    assert np.all(np.asarray(got)[idle] == 0.0)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    # a work list built once for a step's layers gives the same output;
+    # one at another tile geometry (the default: 6 pages a tile) is
+    # ignored, and the call builds its own
+    from repro.kernels.attention.decode import decode_work
+    for work in (decode_work(lengths, table, page=PAGE_W,
+                             pages_per_tile=PPT_W, window=window),
+                 dispatch.decode_work(lengths, table, page=PAGE_W,
+                                      window=window)):
+        again = decode_op(q, kp, vp, table, lengths, *scales, layer=layer,
+                          window=window, pages_per_tile=PPT_W,
+                          plan="heuristic", work=work)
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+def test_decode_work_list_orders_live_pairs_and_host_twin_agrees():
+    """The work list holds each live (row, tile) pair once, by row then
+    tile, flags a row's first and last tile, starts a windowed row at the
+    tile of its oldest visible key and repeats the last live item up to
+    its static capacity; the host's count of its items agrees."""
+    from repro.kernels.attention.decode import FIRST, LAST, decode_work_list
+    fl = FIRST | LAST
+    lengths = [0, 17, 0, 16, 1, 48]
+    n_tiles, tile = NP_W // PPT_W, PPT_W * PAGE_W
+    expect = {
+        0: [(1, 0, FIRST), (1, 1, LAST), (3, 0, fl), (4, 0, fl),
+            (5, 0, FIRST), (5, 1, 0), (5, 2, LAST)],
+        # keys 6..16 of row 1 span tiles 0-1; row 5's 37..47 tile 2 alone
+        11: [(1, 0, FIRST), (1, 1, LAST), (3, 0, fl), (4, 0, fl),
+             (5, 2, fl)],
+    }
+    for window, items in expect.items():
+        row, tl, flag, n_work = decode_work_list(
+            jnp.asarray(lengths), n_tiles, tile, window)
+        n = len(items)
+        assert int(n_work) == n
+        got = list(zip(np.asarray(row).tolist(), np.asarray(tl).tolist(),
+                       np.asarray(flag).tolist()))
+        assert len(got) == len(lengths) * n_tiles
+        assert got[:n] == items
+        assert set(got[n:]) == {items[-1]}
+    assert int(decode_work_list(jnp.zeros((4,), jnp.int32), 3, 16)[3]) == 0
+    # the host count agrees at the serving geometry: 8 pages of 64 a tile
+    serve = [0, 1, 512, 513, 8192, 700]
+    for window, n in ((0, 1 + 1 + 2 + 16 + 2), (600, 1 + 1 + 2 + 2 + 2)):
+        assert int(decode_work_list(jnp.asarray(serve), 16, 512,
+                                    window)[3]) == n
+        assert dispatch.decode_work_count(serve, 128, 64, window) == n
+
+
+def test_decode_step_builds_the_work_list_once(monkeypatch):
+    """The layers of one paged decode step read the same lengths and
+    table, so the step builds the decode kernel's work list once for each
+    window its layers use, outside the layers, and not once a layer."""
+    from repro.kernels.attention import decode as decode_mod
+    from repro.kernels.attention import ops as attention_ops
+    built = []
+
+    def counted(fn):
+        def wrap(*args, **kwargs):
+            built.append(kwargs.get("window", 0))
+            return fn(*args, **kwargs)
+        return wrap
+
+    monkeypatch.setattr(decode_mod, "decode_work",
+                        counted(decode_mod.decode_work))
+    monkeypatch.setattr(attention_ops, "_decode_work",
+                        counted(attention_ops._decode_work))
+    page, slots, max_len = 4, 2, 32
+    cfg = _tiny_cfg("gemma3-4b", dispatch="kernels")   # local + global
+    model = Model(cfg, dt=DtypePolicy(compute=jnp.float32),
+                  opts=ExecOptions(mode="run"))
+    params = model.init(jax.random.key(0))
+    cache = model.init_paged_cache(slots, max_len, page)
+    table = jnp.arange(1, 1 + slots * (max_len // page),
+                       dtype=jnp.int32).reshape(slots, -1)
+    jax.eval_shape(lambda c: model.decode_step(
+        params, c, {"tokens": jnp.zeros((slots, 1), jnp.int32)},
+        jnp.int32(0), paged=(jnp.asarray([5, 0], jnp.int32), table)),
+        cache)
+    kinds = (*model.layout.prefix, *model.layout.period, *model.layout.tail)
+    assert sum(m in ("attn", "swa") for m, _ in kinds) > 2
+    assert sorted(built) == [0, cfg.window]
+
+
 def test_decode_tuned_plan_consumed(tmp_path, monkeypatch):
     """A seeded exact-shape decode plan is picked up by the kernel route
     (lookup counters prove the cache was consulted)."""
@@ -329,10 +453,12 @@ def test_paged_prefill_decode_matches_dense_forward(arch, policy, layout):
     for _ in range(4):                     # teacher-forced ragged decode
         nxt = int(np.argmax(np.asarray(logits[0])))
         seq.append(nxt)
+        # a copy: the host buffer may still be read after the call
+        # returns, and ``lengths`` is bumped right below
         dl, cache = model.decode_step(
             params, cache, {"tokens": jnp.asarray([[nxt], [0]], jnp.int32)},
             jnp.int32(0),
-            paged=(jnp.asarray(lengths), jnp.asarray(table)))
+            paged=(jnp.asarray(lengths.copy()), jnp.asarray(table)))
         lengths[0] += 1
         full = model.forward(params, {"tokens": jnp.asarray(seq)[None]})
         np.testing.assert_allclose(np.asarray(dl[0], np.float32),

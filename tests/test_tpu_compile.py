@@ -59,6 +59,20 @@ def _compile(fn, *specs):
     return compiled.as_text()
 
 
+def _pallas_grids(fn, *specs):
+    """The grid mappings of every ``pallas_call`` that tracing ``fn``
+    binds, nested jits included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["grid_mapping"]
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from walk(inner)
+    return list(walk(jax.make_jaxpr(fn)(*specs).jaxpr))
+
+
 def _spec(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -85,6 +99,10 @@ def test_decode_attention_compiles(one_chip, dtype):
 
     assert "tpu_custom_call" in _compile(fn, q, kp, vp, table, lengths,
                                          *scales)
+    # the chip route's grid is (Hkv, n_work): its work axis is bounded by
+    # the live work, a traced value
+    grid, = _pallas_grids(fn, q, kp, vp, table, lengths, *scales)
+    assert grid.num_dynamic_grid_bounds == 1
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
@@ -130,6 +148,10 @@ def test_tp4_shard_kernels_compile(one_chip, op):
 
     assert "tpu_custom_call" in _compile(fn, q, pool, pool, table, rows,
                                          layer)
+    grid, = _pallas_grids(fn, q, pool, pool, table, rows, layer)
+    # decode walks the live work on a dynamic bound; prefill keeps its
+    # static (b, hkv, n_tiles) grid
+    assert grid.num_dynamic_grid_bounds == (op == "decode")
 
 
 def test_flash_forward_with_residuals_compiles(one_chip):

@@ -153,6 +153,24 @@ def test_counters_match_the_arrays_that_cross(served, model_and_params):
         assert got == logits
 
 
+def test_decode_tiles_count_the_kernels_live_tiles(model_and_params):
+    """``decode_tiles`` is one layer's grid steps per kv head in the
+    decode kernel: the decoding rows' live KV tiles (here 8 pages of 4
+    tokens, 32 tokens a tile) at their cached length plus the new token,
+    and nothing for the idle slot."""
+    model, params = model_and_params
+    sched = PagedScheduler(model, params, slots=SLOTS, max_len=64,
+                           page_size=PAGE, log=None)
+    engine = ContinuousEngine(sched, clock="tick", log=None)
+    engine.run(trace_stream([{"t": 0.0, "prompt_len": 29, "max_new": 6}],
+                            vocab_size=model.cfg.vocab_size, seed=5))
+    decode = [r for r in tracing.records()
+              if r.engine == engine.trace_id and "decode" in r.spans]
+    assert [r.counters["decode_rows"] for r in decode] == [1] * 5
+    # cached lengths 29..33: the row's 30th..34th key crosses into tile 2
+    assert [r.counters["decode_tiles"] for r in decode] == [1, 1, 1, 2, 2]
+
+
 def test_executor_times_are_launch_plus_wait(served):
     engine, _, recs = served
     ex = engine.executor
